@@ -15,13 +15,15 @@ contribution loop (``fused_rounds=False``) and the fused cohort round-step
 Every non-SecAgg fused cell also runs SPMD on the ``shard`` backend and the
 report gains a ``shard`` column (``shard_vs_ideal`` wall ratio per cell) —
 the trajectory record for carrying the fused path onto the pod fast path.
-Shard cells are measured in a SUBPROCESS that forces
+On the CPU, shard cells are measured in a SUBPROCESS that forces
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for itself: forcing
 host devices in the *parent* would split the XLA CPU thread pool and slow
 every baseline cell, silently breaking the artifact's comparability with
 earlier trajectory points.  On forced host devices the "mesh" shares one
 CPU's cores, so the shard ratio records collective overhead, not a speedup
-claim.
+claim.  Off the CPU, shard cells run in this process over the real devices
+(a child cannot use the accelerator the parent holds), and fail loudly
+where there are fewer than two.
 
 ``python benchmarks/hotpath.py`` writes ``BENCH_hotpath.json`` (the
 committed artifact).  ``--smoke`` runs tiny shapes and *asserts* the fused
@@ -49,9 +51,12 @@ import subprocess
 import sys
 import time
 
+import jax
+
 import repro.arms as arms
 from repro.arms import backends as backends_lib
 from repro.arms import fused
+from repro.compile_cache import enable_compilation_cache
 from repro.core.dp import DPConfig
 from repro.data.synthetic import make_gemini_like
 from repro.models.tabular import linear_model
@@ -141,9 +146,11 @@ _SHARD_DEVICES = 8
 
 def _measure_shard_cell(arm: str, h: int, r_lo: int, r_hi: int,
                         repeats: int) -> dict:
-    """One shard cell, measured in a subprocess that forces its own host
-    devices — the parent process stays unflagged so baseline cells keep
-    the full XLA CPU thread pool (trajectory comparability)."""
+    """One shard cell on the CPU, measured in a subprocess that forces its
+    own host devices — the parent process stays unflagged so baseline cells
+    keep the full XLA CPU thread pool (trajectory comparability).  Off the
+    CPU the cell runs in this process over the real devices: a child could
+    not use the accelerator the parent holds."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={_SHARD_DEVICES}"
@@ -178,7 +185,7 @@ def collect(hs: list[int], r_lo: int, r_hi: int, repeats: int,
                 # never under SecAgg (the capabilities rule the pair out)
                 plans.append(("shard", True))
             for backend, fused_rounds in plans:
-                if backend == "shard":
+                if backend == "shard" and jax.default_backend() == "cpu":
                     row = _measure_shard_cell(arm, h, r_lo, r_hi, repeats)
                 else:
                     row = measure(arm, h, use_secagg=secagg,
@@ -520,6 +527,7 @@ def main(argv: list[str] | None = None) -> int:
                    metavar=("R_LO", "R_HI"))
     p.add_argument("--shard-cell", help=argparse.SUPPRESS)  # subprocess mode
     args = p.parse_args(argv)
+    enable_compilation_cache()
 
     if args.shard_cell:
         # child mode: this process was spawned with forced host devices to

@@ -10,6 +10,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compilation_cache
+
 MODULES = [
     ("gemini_utility", "Fig 2c / Supp T4-5: GEMINI mortality (4 arms)"),
     ("pancreas_utility", "Fig 3c / Supp T6-7: pancreas cell typing (4 arms)"),
@@ -31,6 +33,7 @@ def main() -> None:
     p.add_argument("--only", default=None,
                    help="comma-separated module names to run")
     args = p.parse_args()
+    enable_compilation_cache()
 
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

@@ -41,6 +41,7 @@ import jax
 import numpy as np
 
 from repro.instrument import jit_dispatches, reset_jit_dispatches
+from repro.compile_cache import enable_compilation_cache
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.handoff import CheckpointPublisher, CheckpointWatcher
 from repro.serve.metrics import render_markdown, summarize
@@ -218,6 +219,7 @@ def main(argv=None) -> int:
     p.add_argument("--max-len", type=int, default=96)
     p.add_argument("--requests", type=int, default=40)
     args = p.parse_args(argv)
+    enable_compilation_cache()
 
     if args.smoke:
         args.rates, args.slots = [4.0, 16.0], 2

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.arms as arms
+from repro.compile_cache import enable_compilation_cache
 from repro.core.dp import DPConfig
 from repro.core.secagg import DropoutRobustSession, SecAggConfig
 from repro.data.synthetic import make_gemini_like
@@ -126,6 +127,7 @@ def run(fast: bool = True) -> list[dict]:
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     header = (f"{'arm':<10} {'sim wall (s)':>12} {'bytes on wire':>14} "
               f"{'rounds':>6} {'epsilon':>8} {'accuracy':>8} {'recov':>5}")
     rows = run(fast=True)

@@ -64,10 +64,15 @@ def _decode_kernel(index_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
+# KV-cache tile: a cache longer than this must be a whole number of tiles
+DEFAULT_BLOCK_K = 512
+
+
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
 def decode_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, index: jax.Array, *,
-    window: int | None = None, block_k: int = 512, interpret: bool = False,
+    window: int | None = None, block_k: int = DEFAULT_BLOCK_K,
+    interpret: bool = False,
 ) -> jax.Array:
     """q: [B,1,H,D]; k,v: [B,L,KV,D]; index: scalar -> [B,1,H,D]."""
     b, _, h, d = q.shape

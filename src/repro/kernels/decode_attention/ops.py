@@ -4,12 +4,16 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.decode_attention.kernel import (
+    DEFAULT_BLOCK_K,
+    decode_attention_pallas,
+)
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
 
 def decode_attention(q, k, v, index, *, window: int | None = None,
-                     block_k: int = 512, force_kernel: bool = False):
+                     block_k: int = DEFAULT_BLOCK_K,
+                     force_kernel: bool = False):
     """Single-query decode attention. TPU -> Pallas; CPU -> oracle."""
     backend = jax.default_backend()
     if backend == "tpu":

@@ -37,7 +37,8 @@ def _ghost_norm_kernel(a_s_ref, a_t_ref, g_s_ref, g_t_ref, out_ref):
                              preferred_element_type=jnp.float32)  # [bs, bt]
     gg = jax.lax.dot_general(g_s, g_t, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [bs, bt]
-    out_ref[0, 0] += jnp.sum(aa * gg)
+    # a (1, 1, 1) vector store: Mosaic refuses scalar stores into VMEM
+    out_ref[...] += jnp.sum(aa * gg).reshape(1, 1, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_t", "interpret"))
@@ -70,8 +71,10 @@ def ghost_norm_pallas(
             pl.BlockSpec((1, block_s, d_out), lambda b_, s_, t_: (b_, s_, 0)),
             pl.BlockSpec((1, block_t, d_out), lambda b_, s_, t_: (b_, t_, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b_, s_, t_: (b_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
+        # the block's last two dims equal the array's (1, 1), as the TPU
+        # tiling rule requires; a (1, 1) block over a (b, 1) array is refused
+        out_specs=pl.BlockSpec((1, 1, 1), lambda b_, s_, t_: (b_, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.float32),
         interpret=interpret,
     )(a, a, g, g)
-    return out[:, 0]
+    return out[:, 0, 0]

@@ -2,8 +2,11 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ghost_norm.kernel import ghost_norm_pallas
 from repro.kernels.ghost_norm.ref import ghost_norm_ref
@@ -44,6 +47,40 @@ def ghost_norm_blocked(a: jax.Array, g: jax.Array,
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _kernel(block_s: int, block_t: int, interpret: bool):
+    """The Pallas kernel as the dispatcher calls it.
+
+    Each example's norm is independent, so a ``vmap`` (the fused round's,
+    over silos) folds into the kernel's own example axis.  Under a mesh
+    (``jax.set_mesh``, as the ``shard`` backend stages its programs) the
+    kernel runs in a ``shard_map`` on each device's slice of the examples:
+    the compiler cannot partition a Mosaic kernel itself.
+    """
+    kernel = functools.partial(ghost_norm_pallas, block_s=block_s,
+                               block_t=block_t, interpret=interpret)
+
+    @jax.custom_batching.custom_vmap
+    def call(a, g):
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
+            return kernel(a, g)
+        spec = P(mesh.axis_names) if a.shape[0] % mesh.size == 0 else P()
+        # check_vma off: the kernel's out_shape declares no varying axes
+        return jax.shard_map(kernel, in_specs=(spec, spec), out_specs=spec,
+                             check_vma=False)(a, g)
+
+    @call.def_vmap
+    def _fold(axis_size, in_batched, a, g):
+        a, g = (x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                for x, batched in zip((a, g), in_batched))
+        out = call(a.reshape((-1,) + a.shape[-2:]),
+                   g.reshape((-1,) + g.shape[-2:]))
+        return out.reshape(a.shape[:-2]), True
+
+    return call
+
+
 def ghost_norm(a: jax.Array, g: jax.Array, *, block_s: int = 128,
                block_t: int = 128, force_kernel: bool = False,
                prefer_oracle: bool = False) -> jax.Array:
@@ -58,11 +95,8 @@ def ghost_norm(a: jax.Array, g: jax.Array, *, block_s: int = 128,
     where the blocked path is cheapest.
     """
     backend = jax.default_backend()
-    if backend == "tpu":
-        return ghost_norm_pallas(a, g, block_s=block_s, block_t=block_t)
-    if force_kernel:
-        return ghost_norm_pallas(a, g, block_s=block_s, block_t=block_t,
-                                 interpret=True)
+    if backend == "tpu" or force_kernel:
+        return _kernel(block_s, block_t, interpret=backend != "tpu")(a, g)
     if prefer_oracle and a.ndim == 3:
         return ghost_norm_ref(a, g)
     return ghost_norm_blocked(a, g)

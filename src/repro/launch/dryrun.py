@@ -54,7 +54,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t0 = time.time()
     program = build_program(cfg, shape_name, mesh, policy=policy, dp_mode=dp_mode)
     donate = (1,) if program.kind == "decode" else ()
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else _null():
+    with jax.set_mesh(mesh):
         lowered = jax.jit(program.fn, donate_argnums=donate).lower(*program.args_sds)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -136,14 +136,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         f"bottleneck={terms['bottleneck']}"
     )
     return record
-
-
-class _null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
 
 
 def main() -> None:

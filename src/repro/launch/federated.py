@@ -94,11 +94,14 @@ class MeshExecutor:
             ShardingPolicy(fsdp=False, tp=True)
             if "model" in mesh.axis_names else None
         )
-        self._marks: dict[int, tuple[Any, NamedSharding]] = {}
+        # id -> (array, placement, is a cohort batch rather than a param)
+        self._marks: dict[int, tuple[Any, NamedSharding, bool]] = {}
         self._staged: dict[Any, Any] = {}
         self.sharded_puts = 0  # placements that actually split an axis
         self.participant_shards = 0  # cohorts split over ("pod","data")
         self.param_shards = 0  # param leaves placed over ("model",)
+        # device -> index of its shard, for the last cohort batch placed
+        self.cohort_layout: dict = {}
 
     # -- hooks consumed by repro.arms.fused -----------------------------------
 
@@ -118,14 +121,14 @@ class MeshExecutor:
         if self._pod_mesh and arr.shape[0] % self.data_size == 0:
             spec = P(*[self._axis_entry if d == 0 else None
                        for d in range(arr.ndim)])
-            self._marks[id(arr)] = (arr, NamedSharding(self.mesh, spec))
+            self._marks[id(arr)] = (arr, NamedSharding(self.mesh, spec), True)
             self.participant_shards += 1
             return
         if arr.shape[axis] % self.data_size:
             return  # replication fallback (same rule as launch/sharding.py)
         spec = P(*[self._axis_entry if d == axis else None
                    for d in range(arr.ndim)])
-        self._marks[id(arr)] = (arr, NamedSharding(self.mesh, spec))
+        self._marks[id(arr)] = (arr, NamedSharding(self.mesh, spec), True)
 
     def mark_params(self, params) -> None:
         """Declare model params for TP placement over the ``model`` axis.
@@ -140,7 +143,7 @@ class MeshExecutor:
         for leaf, sh in zip(jax.tree_util.tree_leaves(params),
                             jax.tree_util.tree_leaves(specs)):
             if sh.spec != P(*([None] * leaf.ndim)):
-                self._marks[id(leaf)] = (leaf, sh)
+                self._marks[id(leaf)] = (leaf, sh, False)
                 self.param_shards += 1
 
     def begin_round(self) -> None:
@@ -158,7 +161,10 @@ class MeshExecutor:
                              **jkw)
             self._staged[wrapper] = staged
         placed = jax.tree_util.tree_map(self._place_leaf, (args, kwargs))
-        return staged(*placed[0], **placed[1])
+        # the mesh in context lets kernels the compiler cannot partition
+        # (Pallas on TPU) run per device under a shard_map
+        with jax.set_mesh(self.mesh):
+            return staged(*placed[0], **placed[1])
 
     def _place_leaf(self, leaf):
         if isinstance(leaf, (bool, int, float)):
@@ -166,7 +172,11 @@ class MeshExecutor:
         mark = self._marks.get(id(leaf))
         if mark is not None:
             self.sharded_puts += 1
-            return jax.device_put(leaf, mark[1])
+            placed = jax.device_put(leaf, mark[1])
+            if mark[2]:
+                self.cohort_layout = {s.device: s.index
+                                      for s in placed.addressable_shards}
+            return placed
         return jax.device_put(leaf, self._replicated)
 
 

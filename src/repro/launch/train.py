@@ -25,6 +25,7 @@ from repro.configs.base import dense_stack
 from repro.core.accountant import RDPAccountant
 from repro.data import make_lm_stream
 from repro.launch import sharding as sh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import ShardedProgram
 from repro.models import transformer as tf
 from repro.models.layers import activation_sharding
@@ -49,17 +50,6 @@ def scaled_config(arch: str, scale: str):
     raise ValueError(scale)
 
 
-def build_mesh_for_host():
-    n = len(jax.devices())
-    model = 1
-    data = n
-    while data % 2 == 0 and model < 2 and data > 1:
-        if data // 2 >= 1:
-            data //= 2
-            model *= 2
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=list(ARCHITECTURES), default="smollm-360m")
@@ -81,7 +71,7 @@ def main() -> None:
     cfg = scaled_config(args.arch, args.scale)
     if args.lr:
         cfg = cfg.replace(lr=args.lr)
-    mesh = build_mesh_for_host()
+    mesh = make_host_mesh()
     policy = sh.ShardingPolicy()
     print(f"mesh={dict(mesh.shape)} arch={args.arch} scale={args.scale} "
           f"dp={'off' if args.no_dp else 'on'}")

@@ -27,6 +27,8 @@ import sys
 import time
 from pathlib import Path
 
+from repro.compile_cache import enable_compilation_cache
+
 
 def _ints(s: str) -> list[int]:
     return [int(x) for x in s.split(",") if x]
@@ -152,6 +154,7 @@ def main(argv: list[str] | None = None) -> int:
                         "is byte-identical")
     p.add_argument("--out", default="BENCH_population.json")
     args = p.parse_args(argv)
+    enable_compilation_cache()
 
     arms = [a for a in args.arms.split(",") if a]
     records = []

@@ -23,6 +23,7 @@ import sys
 import repro.arms as arms
 import repro.obs as obs
 from repro.arms import backends as backends_lib
+from repro.compile_cache import enable_compilation_cache
 from repro.core.dp import DPConfig
 from repro.data.synthetic import make_gemini_like
 # re-exported for pre-refactor callers; canonical home is the model zoo
@@ -128,6 +129,7 @@ def main(argv: list[str] | None = None) -> int:
                    help="record obs spans/counters + privacy ledger and "
                         "export events/ledger/Chrome trace into DIR")
     args = p.parse_args(argv)
+    enable_compilation_cache()
 
     if args.list:
         print("arms:")

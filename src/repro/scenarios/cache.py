@@ -1,9 +1,12 @@
 """Content-addressed on-disk cache for sweep cell results.
 
-One JSON file per cell, named by ``ScenarioSpec.spec_hash()``.  Re-running a
-sweep therefore only executes new/changed cells — a sweep interrupted at
-cell 40/112 resumes where it left off, and editing one axis value only
-invalidates the cells it touches.
+One JSON file per (cell, device), named by ``ResultCache.key``: the
+``ScenarioSpec.spec_hash()`` together with the platform and device kind this
+process computes on.  Re-running a sweep therefore only executes new/changed
+cells — a sweep interrupted at cell 40/112 resumes where it left off, and
+editing one axis value only invalidates the cells it touches — while a row
+computed on one device is never served as another's (a CPU row copied along
+with a checkout is a miss on the chip).  Every row names its ``device``.
 
 Robustness contract (tested in ``tests/test_scenarios.py``): a corrupted or
 stale entry (unparseable JSON, schema mismatch, key/spec mismatch, missing
@@ -13,11 +16,13 @@ never an exception and never silently wrong data.
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
 from pathlib import Path
 
+from repro.canon import content_hash
 from repro.scenarios.spec import ScenarioSpec
 
 logger = logging.getLogger(__name__)
@@ -30,8 +35,17 @@ DEFAULT_CACHE_DIR = ".sweep_cache"
 _REQUIRED_RESULT_KEYS = frozenset(
     {"name", "arm", "backend", "hospitals", "model_size", "model_params",
      "rounds_completed", "epsilon", "accuracy", "wall_clock",
-     "bytes_on_wire", "recoveries"}
+     "bytes_on_wire", "recoveries", "device"}
 )
+
+
+def device_identity() -> dict:
+    """The device this process computes on: what every result row names."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count()}
 
 
 class ResultCache:
@@ -41,8 +55,18 @@ class ResultCache:
         self.root = Path(root)
         self.root.mkdir(parents=True, exist_ok=True)
 
+    @functools.cached_property
+    def device(self) -> dict:
+        return device_identity()
+
+    def key(self, spec: ScenarioSpec) -> str:
+        """The spec's hash bound to this process's platform and device kind."""
+        return content_hash({"spec": spec.spec_hash(),
+                             "platform": self.device["platform"],
+                             "kind": self.device["kind"]}, chars=20)
+
     def path(self, spec: ScenarioSpec) -> Path:
-        return self.root / f"{spec.spec_hash()}.json"
+        return self.root / f"{self.key(spec)}.json"
 
     def get(self, spec: ScenarioSpec) -> dict | None:
         """The cached result for ``spec``, or None (miss / evicted)."""
@@ -53,8 +77,8 @@ class ResultCache:
             entry = json.loads(path.read_text())
             if entry["schema"] != CACHE_SCHEMA:
                 raise ValueError(f"schema {entry['schema']} != {CACHE_SCHEMA}")
-            if entry["key"] != spec.spec_hash():
-                raise ValueError("key does not match spec hash")
+            if entry["key"] != self.key(spec):
+                raise ValueError("key does not match spec hash and device")
             result = entry["result"]
             missing = _REQUIRED_RESULT_KEYS - set(result)
             if missing:
@@ -76,7 +100,7 @@ class ResultCache:
         path = self.path(spec)
         entry = {
             "schema": CACHE_SCHEMA,
-            "key": spec.spec_hash(),
+            "key": self.key(spec),
             "spec": spec.to_dict(),
             "result": result,
         }

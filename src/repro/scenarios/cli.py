@@ -19,6 +19,7 @@ import os
 import sys
 
 import repro.obs as obs
+from repro.compile_cache import enable_compilation_cache
 from repro.scenarios import grid as grid_lib
 from repro.scenarios import presets as presets_lib
 from repro.scenarios import report as report_lib
@@ -106,6 +107,7 @@ def main(argv: list[str] | None = None) -> int:
                         "BENCH rows) and export artifacts into DIR; "
                         "inline cells only — pool workers do not record")
     args = p.parse_args(argv)
+    enable_compilation_cache()
     if args.obs:
         obs.enable()
 

@@ -6,18 +6,16 @@ metrics dict.  ``run_sweep`` drives a list of specs through the result cache:
 hits are served from disk, misses execute — inline for ``jobs=1``, else on a
 spawn-context process pool (JAX initialised in this process must not be
 forked) — and every fresh result is persisted, making sweeps resumable.
+The pool exists only on the CPU: an accelerator belongs to one process at a
+time, and this one holds it, so elsewhere every cell runs inline.
 
 JAX-heavy imports happen inside functions: a fully-cached sweep never
 builds models, data or backends (it still pays the one arm-registry import
 that sweep-axis expansion needs — see ``grid._registered_arms``).
 
-Alongside the *result* cache sits a persistent *compilation* cache
-(``<result-cache-root>/jit-cache``, DESIGN.md §7): every pool worker is a
-fresh spawn-context process, and before it, each worker re-traced and
-re-compiled programs every other worker (and every previous sweep) had
-already built.  Wiring JAX's persistent compilation cache into the worker
-initializer makes compiled programs a sweep-level artifact: cell N's
-compile is cell N+1's disk hit, across processes and across invocations.
+Pool workers turn on the persistent compilation cache
+(``repro.compile_cache``) in their initializer, so cell N's compile is cell
+N+1's disk hit, across processes and across invocations.
 """
 
 from __future__ import annotations
@@ -25,41 +23,18 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import sys
 import time
 from concurrent.futures import ProcessPoolExecutor
 from typing import Callable, Sequence
 
 import repro.obs as obs
+from repro.compile_cache import enable_compilation_cache
 from repro.scenarios import presets as presets_lib
-from repro.scenarios.cache import ResultCache
+from repro.scenarios.cache import ResultCache, device_identity
 from repro.scenarios.spec import ScenarioSpec
 
 logger = logging.getLogger(__name__)
-
-JIT_CACHE_SUBDIR = "jit-cache"
-
-
-def enable_compilation_cache(cache_root: str) -> None:
-    """Point JAX's persistent compilation cache under the sweep cache.
-
-    Zero thresholds: sweep programs are many and small, and the default
-    min-compile-time / min-entry-size gates would skip exactly the tiny
-    programs whose per-worker recompiles dominate a parallel sweep.
-    Failure is non-fatal (older jaxlibs): the sweep still runs, it just
-    recompiles as before.
-    """
-    import os
-
-    import jax
-
-    path = os.path.join(str(cache_root), JIT_CACHE_SUBDIR)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception as e:  # pragma: no cover - depends on jax version
-        logger.warning("persistent compilation cache unavailable: %s", e)
-
 
 def build_scenario(spec: ScenarioSpec):
     """(model, silos, cfg, nodes, topo) — everything ``repro.arms.run`` needs.
@@ -119,6 +94,12 @@ def build_scenario(spec: ScenarioSpec):
 
 def run_spec(spec: ScenarioSpec) -> dict:
     """Execute one cell and return its plain-JSON metrics."""
+    return run_spec_report(spec)[0]
+
+
+def run_spec_report(spec: ScenarioSpec):
+    """Execute one cell: (its plain-JSON metrics, the arm's ``RunReport``),
+    for callers that check the trained params as well as the row."""
     import jax
     import numpy as np
 
@@ -162,6 +143,7 @@ def run_spec(spec: ScenarioSpec) -> dict:
         "events": int(rep.events),
         "noise_topups": int(rep.noise_topups),
         "host_seconds": host_seconds,
+        "device": device_identity(),
     }
     if spans_before is not None:
         # per-cell host-time phase breakdown (fused dispatch vs aggregate vs
@@ -174,12 +156,7 @@ def run_spec(spec: ScenarioSpec) -> dict:
             if total - (spans_before.get(name) or (0, 0.0))[1] > 0
             and name != "sweep.cell"
         }
-    return row
-
-
-def _pool_init(cache_root: str) -> None:
-    """Worker initializer: persistent jit cache before any JAX import."""
-    enable_compilation_cache(cache_root)
+    return row, rep
 
 
 def _pool_cell(spec_dict: dict) -> dict:
@@ -214,6 +191,8 @@ def run_sweep(
 
     ``runner`` overrides cell execution (tests inject a counting fake; the
     process pool is bypassed whenever a runner is given or ``jobs <= 1``).
+    Off the CPU the pool is bypassed too, and the sweep says so on stderr:
+    a child process cannot use the accelerator this process holds.
     """
     t0 = time.time()
     say = progress or (lambda msg: None)
@@ -231,6 +210,14 @@ def run_sweep(
             pending.append(idx)
     say(f"{len(specs)} cells: {hits} cached, {len(pending)} to run")
 
+    if pending and runner is None and jobs > 1 and len(pending) > 1:
+        platform = cache.device["platform"]
+        if platform != "cpu":
+            print(f"run_sweep: running {len(pending)} cells inline, not on "
+                  f"{jobs} workers: the {platform} device belongs to this "
+                  "process", file=sys.stderr)
+            jobs = 1
+
     if pending:
         if runner is None and jobs > 1 and len(pending) > 1:
             # spawn, not fork: this process has (or will have) a live JAX
@@ -244,8 +231,8 @@ def run_sweep(
             first_error: BaseException | None = None
             with ProcessPoolExecutor(max_workers=min(jobs, len(pending)),
                                      mp_context=ctx,
-                                     initializer=_pool_init,
-                                     initargs=(str(cache.root),)) as pool:
+                                     initializer=enable_compilation_cache
+                                     ) as pool:
                 futures = {
                     pool.submit(_pool_cell, specs[i].to_dict()): i
                     for i in pending
@@ -263,9 +250,6 @@ def run_sweep(
             if first_error is not None:
                 raise first_error
         else:
-            if runner is None:
-                # inline execution compiles in-process; same persistent cache
-                enable_compilation_cache(str(cache.root))
             run_one = runner or run_spec
             for idx in pending:
                 results[idx] = run_one(specs[idx])

@@ -24,6 +24,7 @@ import tempfile
 import threading
 
 import repro.obs as obs
+from repro.compile_cache import enable_compilation_cache
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.serve.handoff import CheckpointWatcher
 from repro.serve.metrics import render_markdown, summarize
@@ -67,6 +68,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compilation_cache()
     rec = obs.enable() if args.obs else None
     engine = ServeEngine(ServeConfig(
         arch=args.arch, slots=args.slots, max_len=args.max_len,

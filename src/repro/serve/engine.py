@@ -43,6 +43,7 @@ import numpy as np
 import repro.obs as obs
 from repro.configs import get_config, get_smoke_config
 from repro.instrument import instrumented_jit
+from repro.kernels.decode_attention.kernel import DEFAULT_BLOCK_K
 from repro.models import transformer as tf
 from repro.serve.traffic import Request
 
@@ -77,6 +78,13 @@ class ServeEngine:
     def __init__(self, cfg: ServeConfig, *, model_cfg=None,
                  params: PyTree | None = None, round_idx: int = -1) -> None:
         self.cfg = cfg
+        block = DEFAULT_BLOCK_K
+        if cfg.decode_kernel and cfg.max_len > block and cfg.max_len % block:
+            raise ValueError(
+                f"max_len={cfg.max_len}: the decode_attention kernel reads the "
+                f"KV cache in tiles of {block}; use max_len <= {block} or a "
+                f"multiple of {block}"
+            )
         if model_cfg is None:
             model_cfg = (get_smoke_config(cfg.arch) if cfg.smoke
                          else get_config(cfg.arch))

@@ -22,10 +22,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json
 import jax
 from repro.launch.dryrun import run_one
+from repro.launch.mesh import make_debug_mesh
 
 arch, shape, multipod = sys.argv[1], sys.argv[2], sys.argv[3] == "1"
-mesh = (jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multipod
-        else jax.make_mesh((4, 2), ("data", "model")))
+mesh = (make_debug_mesh(2, 2, multi_pod=True) if multipod
+        else make_debug_mesh(4, 2))
 rec = run_one(arch, shape, mesh=mesh, out_dir="/tmp/repro_dryrun_test")
 print("RESULT::" + json.dumps({
     "flops": rec["corrected_flops"],

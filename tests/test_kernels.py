@@ -124,6 +124,33 @@ def test_ghost_norm_dispatch_paths_agree(b, s, din, dout, block):
         np.asarray(oracle), rtol=1e-6, atol=1e-6)
 
 
+def test_ghost_norm_kernel_folds_vmap_and_runs_under_a_mesh():
+    """The dispatcher's kernel path: a vmap over silos folds into the
+    kernel's example axis, and under a mesh it runs in a shard_map — both
+    give the oracle's per-example norms."""
+    from repro.kernels.ghost_norm.ops import ghost_norm
+    from repro.launch.mesh import make_debug_mesh
+
+    a = _rand((3, 2, 32, 16), jnp.float32, 21)
+    g = _rand((3, 2, 32, 8), jnp.float32, 22, scale=0.1)
+    oracle = jax.vmap(ghost_norm_ref)(a, g)
+
+    def kernel(x, y):
+        return ghost_norm(x, y, block_s=16, block_t=16, force_kernel=True)
+
+    folded = jax.jit(jax.vmap(kernel))(a, g)
+    np.testing.assert_allclose(np.asarray(folded), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
+    shared_g = jax.vmap(kernel, in_axes=(0, None))(a, g[0])
+    np.testing.assert_allclose(
+        np.asarray(shared_g), np.asarray(jax.vmap(ghost_norm_ref, (0, None))(
+            a, g[0])), rtol=2e-5, atol=2e-5)
+    with jax.set_mesh(make_debug_mesh(1, 1)):
+        on_mesh = jax.jit(jax.vmap(kernel))(a, g)
+    np.testing.assert_allclose(np.asarray(on_mesh), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_ghost_norm_matches_outer_product_norms():
     """Cross-check vs literally materialised per-example weight grads."""
     b, s, din, dout = 3, 16, 8, 5

@@ -102,6 +102,10 @@ def test_named_sweeps_enumerate_live_arm_registry():
 # -- result cache -------------------------------------------------------------
 
 
+_CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
+_TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+
 def _fake_result(spec, **overrides):
     out = {
         "name": spec.name, "key": spec.spec_hash(), "task": spec.task,
@@ -112,6 +116,7 @@ def _fake_result(spec, **overrides):
         "wall_clock": 1.0, "bytes_on_wire": 100.0, "dropout_events": 0,
         "recoveries": 0, "lost_rounds": 0, "events": 10,
         "host_seconds": 0.01,
+        "device": dict(_CPU),
     }
     out.update(overrides)
     return out
@@ -173,10 +178,66 @@ def test_cache_rejects_key_mismatch_and_missing_fields(tmp_path, caplog):
         assert cache.get(spec) is None
     assert not cache.path(spec).exists()  # evicted
     # entry with a valid key but gutted result payload
-    entry = {"schema": 1, "key": spec.spec_hash(), "spec": spec.to_dict(),
+    entry = {"schema": 1, "key": cache.key(spec), "spec": spec.to_dict(),
              "result": {"arm": "fl"}}
     cache.path(spec).write_text(json.dumps(entry))
     assert cache.get(spec) is None
+
+
+def test_cache_row_from_one_device_is_a_miss_on_another(tmp_path,
+                                                         monkeypatch):
+    import repro.scenarios.cache as cache_lib
+
+    spec = ScenarioSpec(name="cell", arm="fl", rounds=2)
+    monkeypatch.setattr(cache_lib, "device_identity", lambda: dict(_CPU))
+    ResultCache(tmp_path).put(spec, _fake_result(spec))
+    assert ResultCache(tmp_path).get(spec) is not None  # same device: hit
+
+    monkeypatch.setattr(cache_lib, "device_identity", lambda: dict(_TPU))
+    on_chip = ResultCache(tmp_path)
+    assert on_chip.get(spec) is None
+    calls = []
+
+    def chip_runner(s):
+        calls.append(s.name)
+        return _fake_result(s, device=dict(_TPU))
+
+    outcome = run_sweep([spec], on_chip, runner=chip_runner)
+    assert (outcome.hits, outcome.misses) == (0, 1) and calls == ["cell"]
+    assert outcome.results[0]["device"] == _TPU
+    assert len(on_chip) == 2  # both devices' rows are kept side by side
+
+
+def test_cache_entry_without_device_is_evicted(tmp_path, caplog):
+    cache = ResultCache(tmp_path)
+    spec = ScenarioSpec(name="cell", arm="fl")
+    row = _fake_result(spec)
+    del row["device"]
+    cache.put(spec, row)
+    with caplog.at_level(logging.WARNING, logger="repro.scenarios.cache"):
+        assert cache.get(spec) is None
+    assert any("device" in r.message for r in caplog.records)
+
+
+def test_sweep_runs_inline_off_the_cpu(tmp_path, monkeypatch, capsys):
+    """A pool worker cannot use the accelerator the parent holds: off the
+    CPU, --jobs > 1 runs every cell in this process and says so."""
+    import repro.scenarios.cache as cache_lib
+    import repro.scenarios.executor as executor_lib
+
+    monkeypatch.setattr(cache_lib, "device_identity", lambda: dict(_TPU))
+
+    def no_pool(*a, **k):
+        raise AssertionError("a process pool was started off the CPU")
+
+    monkeypatch.setattr(executor_lib, "ProcessPoolExecutor", no_pool)
+    ran = []
+    monkeypatch.setattr(executor_lib, "run_spec",
+                        lambda s: ran.append(s.name) or _fake_result(s))
+    specs = [ScenarioSpec(name=f"c{i}", arm="fl", seed=i) for i in range(3)]
+    outcome = run_sweep(specs, ResultCache(tmp_path), jobs=4)
+    assert outcome.misses == 3 and ran == ["c0", "c1", "c2"]
+    assert "inline" in capsys.readouterr().err
 
 
 # -- end-to-end: a real (tiny) sweep through the cache ------------------------

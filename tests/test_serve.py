@@ -47,6 +47,14 @@ def _request(rid=0, prompt_len=6, gen=8, fill=None):
     return Request(rid=rid, arrival=0.0, prompt=prompt, max_new_tokens=gen)
 
 
+def test_engine_rejects_max_len_the_decode_kernel_cannot_tile():
+    with pytest.raises(ValueError, match="tiles of 512"):
+        _engine(max_len=600)
+    assert _engine(slots=1, max_len=1024).cache is not None
+    plain = ServeEngine(ServeConfig(slots=1, max_len=600, decode_kernel=False))
+    assert plain.cfg.max_len == 600
+
+
 # -- the O(1)-dispatch invariant ---------------------------------------------
 
 

@@ -31,10 +31,11 @@ from repro.configs import get_smoke_config
 from repro.core import dp as dp_lib
 from repro.core.ghost import ghost_clipped_grad_sum
 from repro.launch import sharding as sh
+from repro.launch.mesh import make_debug_mesh
 from repro.models import transformer as tf
 from repro.models.layers import activation_sharding
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_debug_mesh(4, 2)
 policy = sh.ShardingPolicy()
 results = {}
 
