@@ -1,0 +1,217 @@
+"""Plain reference of the first rounds of a DeCaPH run on the ideal backend.
+
+Follows the protocol the configuration states, written from the paper's
+Steps 1-7 and independent of the program under test:
+
+* preparation: every hospital's features are standardised by the pooled
+  mean and standard deviation (computed here in float64);
+* weights: He-normal MLP weights and zero biases from ``jax.random.key(seed)``
+  (one split per layer, the first half of each layer's split for ``w``);
+* round ``t``: Poisson sampling at the shared rate ``B / N`` from one host
+  ``numpy.random.default_rng(stream_seed)`` stream, hospitals in ascending
+  order; per-example gradients clipped to ``C`` by their global L2 norm and
+  summed; each hospital adds its noise share N(0, (C sigma)^2 / H) drawn from
+  ``fold_in(fold_in(key(stream_seed), 17 + t), hospital)``, one split per leaf in
+  pytree order; the shares are summed in ascending hospital order (with
+  SecAgg: each fixed-point encoded with ``frac_bits`` fractional bits, the
+  codes summed and decoded, since the pairwise masks cancel in the field
+  sum); the sum is divided by the aggregate batch and applied by SGD.
+
+``dtype`` float32 runs the model's arithmetic at ``highest`` matmul
+precision; bfloat16 is the control, which has to fail the comparison.
+Rows are processed in chunks of ``CHUNK`` so a published-width model fits.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+NOISE_SALT = 17
+CHUNK = 16
+
+
+def names(tree) -> list[str]:
+    """Leaf names (``"l0.w"``, ...) in pytree order."""
+    return [".".join(str(getattr(k, "key", k)) for k in path)
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def flat(tree) -> dict[str, np.ndarray]:
+    """``{"l0.w": array, ...}`` host copy of a parameter tree."""
+    host = jax.device_get(tree)
+    return dict(zip(names(host), map(np.asarray,
+                                     jax.tree_util.tree_leaves(host))))
+
+
+def init_params(sizes, seed: int) -> dict:
+    keys = jax.random.split(jax.random.key(seed), len(sizes) - 1)
+    params = {}
+    for i in range(len(sizes) - 1):
+        k_w, _ = jax.random.split(keys[i])
+        w = jax.random.normal(k_w, (sizes[i], sizes[i + 1]), jnp.float32)
+        params[f"l{i}"] = {"w": w * math.sqrt(2.0 / sizes[i]),
+                           "b": jnp.zeros((sizes[i + 1],), jnp.float32)}
+    return params
+
+
+def standardize(raw):
+    """Pooled mean and std of the features (float64 sums)."""
+    n = sum(len(x) for x, _ in raw)
+    s = sum(np.sum(x, axis=0, dtype=np.float64) for x, _ in raw)
+    sq = sum(np.sum(np.square(x, dtype=np.float64), axis=0) for x, _ in raw)
+    mean = s / n
+    return mean, np.sqrt(np.maximum(sq / n - mean * mean, 0.0)) + 1e-8
+
+
+def _example_loss(params, x, y, task: str, n_layers: int):
+    h = x
+    for i in range(n_layers):
+        h = h @ params[f"l{i}"]["w"] + params[f"l{i}"]["b"]
+        if i < n_layers - 1:
+            h = jnp.maximum(h, 0)
+    if task == "binary":
+        z, y = h[0].astype(jnp.float32), y.astype(jnp.float32)
+        return jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
+    return -jax.nn.log_softmax(h.astype(jnp.float32))[y]
+
+
+@partial(jax.jit, static_argnames=("task", "n_layers", "clip"))
+def _clipped_chunk(params, x, y, mask, *, task, n_layers, clip):
+    """(sum of clipped per-example gradients, sum of losses) over a chunk."""
+
+    def one(x_i, y_i):
+        loss, g = jax.value_and_grad(_example_loss)(params, x_i, y_i, task,
+                                                    n_layers)
+        leaves = jax.tree_util.tree_leaves(g)
+        norm = jnp.sqrt(sum(jnp.sum(jnp.square(a.astype(jnp.float32)))
+                            for a in leaves))
+        factor = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
+        return jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.float32) * factor, g), loss
+
+    g, losses = jax.vmap(one)(x, y)
+    g = jax.tree_util.tree_map(
+        lambda a: jnp.tensordot(mask, a, axes=1,
+                                precision=jax.lax.Precision.HIGHEST), g)
+    return g, jnp.sum(losses * mask)
+
+
+def _noise(seed: int, t: int, idx: int, template, std):
+    key = jax.random.fold_in(jax.random.fold_in(jax.random.key(seed),
+                                                NOISE_SALT + t), idx)
+    leaves, treedef = jax.tree_util.tree_flatten(template)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        jax.random.normal(k, a.shape, jnp.float32) * std
+        for k, a in zip(keys, leaves)])
+
+
+def _quantize(tree, frac_bits: int):
+    """Fixed-point encode: ``round(x * 2^frac_bits)`` as int32.  Exact for
+    these payloads (|x| < 2^15), so the int32 sum wraps as Z_2^32 does."""
+    scale = float(1 << frac_bits)
+    return jax.tree_util.tree_map(
+        lambda a: jnp.round(a * scale).astype(jnp.int32), tree)
+
+
+def _dequantize(tree, frac_bits: int):
+    scale = float(1 << frac_bits)
+    return jax.tree_util.tree_map(
+        lambda a: (a.astype(jnp.float32) / scale), tree)
+
+
+def _tree_add(a, b):
+    return b if a is None else jax.tree_util.tree_map(jnp.add, a, b)
+
+
+@dataclasses.dataclass
+class Trajectory:
+    """What the reference (or the control) produced over its rounds.
+
+    ``params[t]`` is the flat parameter dict after ``t`` rounds (0 = init),
+    ``losses[t]`` the mean over hospitals of each one's mean loss on its
+    draw, ``batches[t]`` the aggregate batch, ``noise0`` the sum of the
+    hospitals' noise shares in the first round (flat dict).
+    """
+
+    params: list[dict]
+    losses: list[float]
+    batches: list[int]
+    noise0: dict
+    rate: float
+
+
+def draws(sizes: list[int], rate: float, seed: int, rounds: int):
+    """Per round, per hospital: the indices of its Poisson draw."""
+    rng = np.random.default_rng(seed)
+    return [[np.nonzero(rng.random(n) < rate)[0] for n in sizes]
+            for _ in range(rounds)]
+
+
+def run(raw, config: dict, traffic: dict, seed: int, stream_seed: int,
+        rounds: int, dtype=jnp.float32) -> Trajectory:
+    """Follow ``rounds`` rounds from the weights of ``seed`` and the draws
+    and noise of ``stream_seed``; see the module docstring."""
+    sizes = config["model"]["sizes"]
+    task = config["model"]["task"]
+    dp = config["dp"]
+    frac_bits = config["secagg_frac_bits"]
+    n_layers = len(sizes) - 1
+    h = len(raw)
+    rate = traffic["batch_size"] / sum(len(x) for x, _ in raw)
+    mean, std = standardize(raw)
+    noise_std = (dp["clip_norm"] * dp["noise_multiplier"]
+                 / jnp.sqrt(float(h)))
+    chunk = partial(_clipped_chunk, task=task, n_layers=n_layers,
+                    clip=float(dp["clip_norm"]))
+    precision = "highest" if dtype == jnp.float32 else "default"
+    params = jax.tree_util.tree_map(lambda a: a.astype(dtype),
+                                    init_params(sizes, seed))
+    traj = Trajectory([flat(params)], [], [], {}, rate)
+    for t, picks in enumerate(draws([len(x) for x, _ in raw], rate,
+                                    stream_seed, rounds)):
+        total = noise_total = None
+        losses = []
+        for i, idx in enumerate(picks):
+            g_sum = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), params)
+            loss_sum = jnp.zeros((), jnp.float32)
+            for lo in range(0, len(idx), CHUNK):
+                rows = idx[lo:lo + CHUNK]
+                x = np.zeros((CHUNK, sizes[0]), np.float32)
+                x[:len(rows)] = (raw[i][0][rows] - mean) / std
+                y = np.zeros((CHUNK,), raw[i][1].dtype)
+                y[:len(rows)] = raw[i][1][rows]
+                mask = np.zeros((CHUNK,), np.float32)
+                mask[:len(rows)] = 1.0
+                with jax.default_matmul_precision(precision):
+                    g, l = chunk(params, jnp.asarray(x, dtype), y, mask)
+                g_sum = _tree_add(g_sum, g)
+                loss_sum = loss_sum + l
+            losses.append(float(loss_sum) / max(len(idx), 1))
+            noise = _noise(stream_seed, t, i, g_sum, noise_std)
+            payload = _tree_add(g_sum, noise)
+            if traffic["secagg"]:
+                payload = _quantize(payload, frac_bits)
+            total = _tree_add(total, payload)   # ascending hospital order
+            noise_total = _tree_add(noise_total, noise)
+        if traffic["secagg"]:
+            total = _dequantize(total, frac_bits)
+        batch = sum(len(idx) for idx in picks)
+        if batch:
+            params = jax.tree_util.tree_map(
+                lambda p, g: (p.astype(jnp.float32)
+                              - config["lr"] * (g / batch)).astype(dtype),
+                params, total)
+        traj.params.append(flat(params))
+        traj.losses.append(float(np.mean(losses)))
+        traj.batches.append(batch)
+        if t == 0:
+            traj.noise0 = flat(noise_total)
+    return traj
