@@ -12,6 +12,7 @@ from typing import Mapping, Sequence
 import jax
 import numpy as np
 
+import repro.obs as obs
 from repro.arms.base import (
     AggregationServices,
     ArmConfig,
@@ -178,9 +179,11 @@ class DeCaPHArm(RoundArm):
         total = services.sum_payloads(
             {i: contributions[i].payload for i in order}
         )
-        grad = tree_div(total, agg_batch)
-        params = sgd_update(params, grad, self.cfg.lr, self.cfg.weight_decay)
-        loss = float(np.mean([contributions[i].loss for i in order]))
+        with obs.span("update", cat="train"):
+            grad = tree_div(total, agg_batch)
+            params = sgd_update(params, grad, self.cfg.lr,
+                                self.cfg.weight_decay)
+            loss = float(np.mean([contributions[i].loss for i in order]))
         return RoundOutcome(params, stepped=True, loss=loss,
                             aggregate_batch=agg_batch)
 

@@ -201,20 +201,30 @@ def build_contributions(
     With ``need_payloads=False`` the payloads stay on device inside the
     fused reduced sum and the per-participant ``payload`` is ``None`` — the
     idealized backend serves the aggregate from the reduced tree instead.
+
+    The ``fused_round.fetch`` span covers the wait for the cohort program
+    and that one transfer; its ``bytes`` is what came to the host.
     """
+    t0 = obs.now()
     loss_vals = None
     if need_payloads:
         if losses is not None:
             payload_stack, loss_vals = jax.device_get((payload_stack, losses))
         else:
             payload_stack = jax.device_get(payload_stack)
+    elif losses is not None:
+        loss_vals = np.asarray(losses)
+    if t0 is not None:
+        fetched = (payload_stack if need_payloads else None, loss_vals)
+        obs.complete("fused_round.fetch", t0, cat="train",
+                     bytes=sum(np.asarray(leaf).nbytes for leaf
+                               in jax.tree_util.tree_leaves(fetched)))
+    if need_payloads:
         slices = [
             jax.tree_util.tree_map(lambda a, s=s: a[s], payload_stack)
             for s in range(len(active))
         ]
     else:
-        if losses is not None:
-            loss_vals = np.asarray(losses)
         slices = [None] * len(active)
     return {
         i: Contribution(

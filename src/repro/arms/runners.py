@@ -90,10 +90,11 @@ class _IdealServices(AggregationServices):
         if self._secure:
             # aggregate mini-batch size ||B^t|| via SecAgg — summed in the
             # field as integers (exact, no float fixed-point round-trip)
-            return secure_sum_ints(
-                list(sizes), n_participants=self._n,
-                seed=self._cfg.seed * 7919 + self._t,
-            )
+            with obs.span("secagg.sizes", cat="secagg"):
+                return secure_sum_ints(
+                    list(sizes), n_participants=self._n,
+                    seed=self._cfg.seed * 7919 + self._t,
+                )
         return int(sum(sizes))
 
     def sum_payloads(self, payloads: Mapping[int, PyTree]) -> PyTree:
@@ -230,19 +231,21 @@ class LocalRunner:
                 fused_reduced=None if secure else reduced,
                 cover=frozenset(contribs),
             )
-            # SecAgg (when on) runs inside aggregate via the services; the
-            # span therefore covers reduce + secure-sum + the model step
+            # SecAgg (when on) runs inside aggregate via the services: the
+            # secagg.sizes, secagg.upload and secagg.sum spans, then the
+            # arm's update
             with obs.span("aggregate", cat="train", t=t, secure=secure):
                 outcome = arm.aggregate(params, contribs, services)
             if outcome.stepped:
                 params = outcome.params
-                arm.account()
-                obs.counter("rounds_completed", 1)
-                obs.ledger_round(arm, round=t, backend=self.backend,
-                                 cohort=active, delivered=contribs,
-                                 bytes_up=model_bytes)
-                logs.append(RoundLog(t, dst, outcome.loss, arm.epsilon(),
-                                     outcome.aggregate_batch))
+                with obs.span("account", cat="dp", t=t):
+                    arm.account()
+                    obs.counter("rounds_completed", 1)
+                    obs.ledger_round(arm, round=t, backend=self.backend,
+                                     cohort=active, delivered=contribs,
+                                     bytes_up=model_bytes)
+                    logs.append(RoundLog(t, dst, outcome.loss, arm.epsilon(),
+                                         outcome.aggregate_batch))
                 if self.on_round is not None:
                     self.on_round(t, params)  # checkpoint-handoff seam
                 if arm.should_stop():
@@ -586,11 +589,9 @@ class SimRunner:
             if session is not None:
                 # one host transfer + one masking pass for the whole cohort
                 # (each participant still *ships* its own ciphertext below)
-                with obs.span("secagg.encode", cat="secagg", t=t,
-                              cohort=len(active)):
-                    ciphers = session.upload_all(
-                        {slot_of[i]: c.payload for i, c in contribs.items()}
-                    )
+                ciphers = session.upload_all(
+                    {slot_of[i]: c.payload for i, c in contribs.items()}
+                )
             work = {}
             for i, c in contribs.items():
                 payload = ciphers[slot_of[i]] if ciphers else c.payload
@@ -643,8 +644,9 @@ class SimRunner:
                 obs.counter("noise_topups", 1)
                 topups += 1
             dl_contribs = {i: contribs[i] for i in delivered}
-            # secure decode (when a session exists) happens inside aggregate
-            # via the services object, so this span covers reduce + decode
+            # with a session, the Shamir cancel and decode run inside
+            # aggregate via the services object (the secagg.sum span), then
+            # the arm's update
             with obs.span("aggregate", cat="train", t=t,
                           secure=session is not None):
                 outcome = arm.aggregate(
@@ -662,15 +664,16 @@ class SimRunner:
                 )
             wire += w
             dropouts += d
-            arm.account()
-            completed += 1
-            obs.counter("rounds_completed", 1)
-            obs.ledger_round(arm, round=t, backend=self.backend,
-                             cohort=active, delivered=delivered,
-                             bytes_up=model_bytes,
-                             topup=topup is not None)
-            logs.append(RoundLog(t, dst, outcome.loss, arm.epsilon(),
-                                 outcome.aggregate_batch))
+            with obs.span("account", cat="dp", t=t):
+                arm.account()
+                completed += 1
+                obs.counter("rounds_completed", 1)
+                obs.ledger_round(arm, round=t, backend=self.backend,
+                                 cohort=active, delivered=delivered,
+                                 bytes_up=model_bytes,
+                                 topup=topup is not None)
+                logs.append(RoundLog(t, dst, outcome.loss, arm.epsilon(),
+                                     outcome.aggregate_batch))
             if self.on_round is not None:
                 self.on_round(t, params)  # checkpoint-handoff seam
             if arm.should_stop():

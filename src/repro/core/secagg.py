@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import repro.obs as obs
+
 PyTree = Any
 
 _FIELD_DTYPE = np.uint32
@@ -264,10 +266,12 @@ def _encode_cohort(
     for leaves in cohort_leaves:
         if len(leaves) != len(template):
             raise ValueError("pytree structure mismatch")
-    cohort_leaves = jax.device_get(cohort_leaves)
-    return np.stack([
-        _flatten_encoded(leaves, template, cfg) for leaves in cohort_leaves
-    ]) if cohort_leaves else np.zeros((0, 0), _FIELD_DTYPE)
+    with obs.span("secagg.encode", cat="secagg"):
+        cohort_leaves = jax.device_get(cohort_leaves)
+        return np.stack([
+            _flatten_encoded(leaves, template, cfg)
+            for leaves in cohort_leaves
+        ]) if cohort_leaves else np.zeros((0, 0), _FIELD_DTYPE)
 
 
 def _split_flat(flat: np.ndarray, template: Sequence[Any]) -> list[np.ndarray]:
@@ -303,14 +307,46 @@ def _masked_cohort_uploads(
     if not values:
         return {}
     order = sorted(values)
-    enc = _encode_cohort(
-        [values[i] for i in order], session._leaves, session.cfg
+    with obs.span("secagg.upload", cat="secagg", participants=len(order),
+                  length=session._length):
+        enc = _encode_cohort(
+            [values[i] for i in order], session._leaves, session.cfg
+        )
+        with np.errstate(over="ignore"):  # modular field arithmetic
+            enc = enc + session._flat_masks()[np.asarray(order, np.intp)]
+        return {
+            i: _split_flat(row, session._leaves)
+            for i, row in zip(order, enc)
+        }
+
+
+def _session_masks(session, **prg) -> np.ndarray:
+    """The session's (n, L) net-mask matrix under a ``secagg.masks`` span:
+    the pad program on the device and the fetch of its result.  ``prg`` is
+    ``_signed_masks``'s ``base_key`` or ``seeds``."""
+    t0 = obs.now()
+    masks = _signed_masks(
+        session.cfg.n_participants, session._length,
+        session._los, session._his,
+        chunk=session.cfg.pad_chunk_pairs, **prg,
     )
-    with np.errstate(over="ignore"):  # modular field arithmetic
-        enc = enc + session._flat_masks()[np.asarray(order, np.intp)]
-    return {
-        i: _split_flat(row, session._leaves) for i, row in zip(order, enc)
-    }
+    if t0 is not None:
+        obs.complete("secagg.masks", t0, cat="secagg",
+                     pairs=len(session._los), bytes=masks.nbytes)
+    return masks
+
+
+def _decoded_tree(session, total: np.ndarray, t0: float | None) -> PyTree:
+    """Decode the field sum back onto the device as the template's pytree,
+    and close the ``secagg.sum`` span opened at ``t0``."""
+    decoded = [
+        jnp.asarray(_decode(t, session.cfg))
+        for t in _split_flat(total, session._leaves)
+    ]
+    if t0 is not None:
+        obs.complete("secagg.sum", t0, cat="secagg",
+                     bytes=sum(x.nbytes for x in decoded))
+    return jax.tree_util.tree_unflatten(session._treedef, decoded)
 
 
 class SecAggSession:
@@ -330,11 +366,7 @@ class SecAggSession:
     def _flat_masks(self) -> np.ndarray:
         """Every participant's net mask, accumulated over pair chunks."""
         if self._masks is None:
-            self._masks = _signed_masks(
-                self.cfg.n_participants, self._length,
-                self._los, self._his,
-                chunk=self.cfg.pad_chunk_pairs, base_key=self._base_key,
-            )
+            self._masks = _session_masks(self, base_key=self._base_key)
         return self._masks
 
     def mask_for(self, i: int) -> list[np.ndarray]:
@@ -367,16 +399,13 @@ class SecAggSession:
                 "missing upload leaves un-cancelled masks in the sum — use "
                 "DropoutRobustSession if participants may drop out"
             )
+        t0 = obs.now()
         _check_uploads(uploads, self._leaves)
         with np.errstate(over="ignore"):  # modular wraparound is the protocol
             total = _stack_ciphertexts(uploads).sum(
                 axis=0, dtype=_FIELD_DTYPE
             )
-        decoded = [
-            jnp.asarray(_decode(t, self.cfg))
-            for t in _split_flat(total, self._leaves)
-        ]
-        return jax.tree_util.tree_unflatten(self._treedef, decoded)
+        return _decoded_tree(self, total, t0)
 
 
 def _check_uploads(
@@ -577,11 +606,7 @@ class DropoutRobustSession:
                 self._pair_seed(int(lo), int(hi))
                 for lo, hi in zip(self._los, self._his)
             ]
-            self._masks = _signed_masks(
-                self.cfg.n_participants, self._length,
-                self._los, self._his,
-                chunk=self.cfg.pad_chunk_pairs, seeds=seeds,
-            )
+            self._masks = _session_masks(self, seeds=seeds)
         return self._masks
 
     def upload(self, i: int, values: PyTree) -> list[np.ndarray]:
@@ -628,6 +653,7 @@ class DropoutRobustSession:
                 f"only {len(survivors)} uploads for threshold "
                 f"{self.threshold}: cannot reconstruct dropped masks"
             )
+        t0 = obs.now()
         _check_uploads([uploads[s] for s in survivors], self._leaves)
         with np.errstate(over="ignore"):
             total = _stack_ciphertexts(
@@ -648,11 +674,7 @@ class DropoutRobustSession:
                 before = np.asarray([j < d for j in survivors])
                 total = total - pads[before].sum(axis=0, dtype=_FIELD_DTYPE)
                 total = total + pads[~before].sum(axis=0, dtype=_FIELD_DTYPE)
-        decoded = [
-            jnp.asarray(_decode(t, self.cfg))
-            for t in _split_flat(total, self._leaves)
-        ]
-        return jax.tree_util.tree_unflatten(self._treedef, decoded)
+        return _decoded_tree(self, total, t0)
 
 
 def secure_sum_with_dropouts(

@@ -24,10 +24,11 @@ thread that opened it, so a concurrent serving thread can never be routed
 through another thread's mesh executor.
 
 When ``repro.obs`` recording is enabled, every dispatch additionally
-feeds the process recorder: a ``jit_dispatches`` counter event plus a
-``jit_dispatch`` span bracketing the launch, which is what lets obs phase
-breakdowns attribute wall time to compiled-program dispatch.  Recording
-off means exactly the pre-obs behavior (a lock, an int, nothing else).
+records a ``jit_dispatch`` span bracketing the launch, which is what lets
+obs phase breakdowns attribute wall time to compiled-program dispatch; the
+number of those spans is the recorded dispatch count (``python -m
+repro.obs`` prints it), so no obs counter repeats it.  Recording off means
+exactly the pre-obs behavior (a lock, an int, nothing else).
 
 ``execution_context`` routes every instrumented call through an installed
 executor (the SPMD ``MeshExecutor`` in ``launch/federated.py``) so a mesh
@@ -95,7 +96,6 @@ def instrumented_jit(fn: Callable, **jit_kwargs) -> Callable:
         if t0 is not None:
             obs.complete("jit_dispatch", t0, cat="jit",
                          fn=getattr(fn, "__name__", "<fn>"))
-            obs.counter("jit_dispatches", 1)
         return out
 
     wrapper.jitted = compiled

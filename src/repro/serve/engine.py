@@ -200,7 +200,6 @@ class ServeEngine:
                                       jnp.asarray(idx, jnp.int32))
             tok0 = int(np.asarray(tok0)[0])
         self.admit_dispatches += 2
-        obs.counter("serve.admits", 1)
         request.t_admit = request.t_first = now
         request.round_at_first = self.serving_round
         request.tokens.append(tok0)
@@ -239,7 +238,6 @@ class ServeEngine:
             nxt = np.asarray(nxt)  # the single per-token host sync
         self.decode_steps += 1
         self.decode_dispatches += 1
-        obs.counter("serve.decode_steps", 1)
         finished: list[Request] = []
         for i, s in enumerate(self.slots):
             if s is None:

@@ -14,6 +14,7 @@ The load-bearing promises (DESIGN.md §11):
 import json
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -152,12 +153,14 @@ def test_instrumented_jit_feeds_obs_counter():
     import jax.numpy as jnp
 
     f = instrumented_jit(lambda x: x * 2)
+    f(jnp.ones(()))  # compile outside the recorded region
+    reset_jit_dispatches()
     with obs.recording() as rec:
         f(jnp.ones(()))
         f(jnp.ones(()))
-    assert rec.counter_totals()["jit_dispatches"] == 2
-    assert sum(e["name"] == "jit_dispatch" for e in rec.events()
-               if e["type"] == "span") == 2
+    # the span count is the recorded dispatch count; no counter repeats it
+    assert _span_count(rec, "jit_dispatch") == jit_dispatches() == 2
+    assert "jit_dispatches" not in rec.counter_totals()
 
 
 def test_event_stream_validation_catches_corruption():
@@ -295,26 +298,111 @@ def test_ledger_ideal_backend_matches_sim_epsilon():
 # -- the zero-overhead contract ----------------------------------------------
 
 
-def test_recording_adds_zero_jit_dispatches():
+@pytest.mark.parametrize("use_secagg", [False, True])
+def test_recording_adds_zero_jit_dispatches(use_secagg):
     """The pinned overhead bound: the fused round loop launches exactly as
-    many compiled programs with recording on as off."""
-    cfg = _cfg(rounds=3)
+    many compiled programs with recording on as off, and trains to the same
+    parameters."""
+    cfg = _cfg(rounds=3, use_secagg=use_secagg)
     model, silos = _make_model(5), _silos()
 
     arms.run("decaph", model, silos, cfg)  # warm the compile caches
     reset_jit_dispatches()
-    arms.run("decaph", model, silos, cfg)
+    off = arms.run("decaph", model, silos, cfg)
     baseline = jit_dispatches()
 
     reset_jit_dispatches()
     with obs.recording() as rec:
-        arms.run("decaph", model, silos, cfg)
+        on = arms.run("decaph", model, silos, cfg)
     recorded = jit_dispatches()
 
     assert baseline > 0
     assert recorded == baseline
-    # and the recorder's own counter agrees with the process counter
-    assert rec.counter_totals()["jit_dispatches"] == recorded
+    # the recorder's spans count the same launches as the process counter
+    assert _span_count(rec, "jit_dispatch") == recorded
+    for a, b in zip(jax.tree_util.tree_leaves(off.params),
+                    jax.tree_util.tree_leaves(on.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the split of a DeCaPH round ----------------------------------------------
+
+# each once per stepped round of an ``ideal`` DeCaPH run with SecAgg on
+_ROUND_SPANS = ("round", "fused_round", "fused_round.fetch", "aggregate",
+                "secagg.sizes", "secagg.upload", "secagg.encode",
+                "secagg.masks", "secagg.sum", "update", "account")
+
+
+def _spans(rec, name=None):
+    return [e for e in rec.events() if e["type"] == "span"
+            and (name is None or e["name"] == name)]
+
+
+def _span_count(rec, name):
+    return len(_spans(rec, name))
+
+
+def test_secagg_round_spans_split_the_aggregate():
+    """Each span of the round's split occurs once per round, nested under
+    the span that causes it, and its ``bytes`` are the arrays' own."""
+    rounds, h = 2, 4
+    model = _make_model(5)
+    with obs.recording() as rec:
+        rep = arms.run("decaph", model, _silos(),
+                       _cfg(rounds=rounds, use_secagg=True))
+    assert rep.rounds_completed == rounds
+    for name in _ROUND_SPANS:
+        assert _span_count(rec, name) == rounds, name
+    depth = {e["name"]: e["depth"] for e in _spans(rec)}
+    assert depth["secagg.encode"] > depth["secagg.upload"]
+    assert depth["secagg.masks"] > depth["secagg.upload"]
+    assert depth["secagg.upload"] > depth["aggregate"] > depth["round"]
+    for name in ("secagg.sizes", "secagg.sum", "update"):
+        assert depth[name] == depth["aggregate"] + 1, name
+    assert depth["fused_round.fetch"] == depth["fused_round"] + 1
+    assert depth["account"] == depth["round"] + 1
+
+    leaves = jax.tree_util.tree_leaves(model.init_fn(jax.random.key(0)))
+    length = sum(np.asarray(x).size for x in leaves)
+    f32 = np.dtype(np.float32).itemsize
+    masks = np.zeros((h, length), np.uint32)   # one field row each
+    for e in _spans(rec, "secagg.upload"):
+        assert e["args"] == {"participants": h, "length": length}
+    for e in _spans(rec, "secagg.masks"):
+        assert e["args"] == {"pairs": h * (h - 1) // 2,
+                             "bytes": masks.nbytes}
+    # the payload stack and the losses come to the host
+    assert [e["args"]["bytes"] for e in _spans(rec, "fused_round.fetch")] \
+        == [h * length * f32 + h * f32] * rounds
+    # the decoded float32 sum goes back to the device
+    assert [e["args"]["bytes"] for e in _spans(rec, "secagg.sum")] \
+        == [length * f32] * rounds
+
+
+def test_no_secagg_span_with_secagg_off():
+    with obs.recording() as rec:
+        arms.run("decaph", _make_model(5), _silos(), _cfg(rounds=2))
+    names = {e["name"] for e in _spans(rec)}
+    assert not {n for n in names if n.startswith("secagg.")}
+    assert {"fused_round.fetch", "update", "account"} <= names
+    # only the losses come to the host
+    assert {e["args"]["bytes"] for e in _spans(rec, "fused_round.fetch")} \
+        == {4 * np.dtype(np.float32).itemsize}
+
+
+def test_sim_secagg_upload_once_per_round_at_one_depth():
+    h = 5
+    with obs.recording() as rec:
+        rep = arms.run("decaph", _make_model(5), _silos(sizes=(120,) * h),
+                       _cfg(rounds=3, use_secagg=True), backend="sim",
+                       nodes=_sim_nodes(h))
+    assert _span_count(rec, "secagg.upload") == rep.rounds_completed == 3
+    assert _span_count(rec, "secagg.sum") == 3
+    assert _span_count(rec, "account") == 3
+    depths: dict[str, set[int]] = {}
+    for e in _spans(rec):
+        depths.setdefault(e["name"], set()).add(e["depth"])
+    assert all(len(d) == 1 for d in depths.values()), depths
 
 
 # -- serve + metrics ----------------------------------------------------------
@@ -328,11 +416,10 @@ def test_serve_engine_emits_obs_counters():
         prompts = np.ones((2, 4), np.int32)
         batch_generate(engine, prompts, gen=3)
     totals = rec.counter_totals()
-    assert totals["serve.admits"] == 2
-    assert totals["serve.decode_steps"] == engine.decode_steps
+    assert _span_count(rec, "serve.admit") == 2
+    assert _span_count(rec, "serve.decode_step") == engine.decode_steps
     assert totals["serve.evictions"] == 2
-    names = {e["name"] for e in rec.events() if e["type"] == "span"}
-    assert {"serve.admit", "serve.decode_step"} <= names
+    assert not {"serve.admits", "serve.decode_steps"} & set(totals)
     validate_events(rec.events())
 
 
