@@ -377,10 +377,13 @@ class RoundArm(Arm):
         Must consume ``rng`` exactly as the ``contribution()`` loop would.
         Return ``None`` to fall back to the per-participant loop (the
         default — arms opt in).  ``need_payloads=False`` means the backend
-        will consume the reduced tree and per-participant payloads may be
-        withheld (stay on device, never unstacked); ``need_reduced=False``
-        means the backend will sum delivered payloads itself (sim
-        transport, SecAgg) and the in-jit reduction may be skipped.
+        will not read per-participant payloads and they may be withheld
+        (stay on device, never unstacked); ``need_reduced=False`` means the
+        backend will sum the payloads itself (sim transport, SecAgg) and
+        the in-jit reduction may be skipped.  The second element is the
+        reduced tree, or — with neither asked for — the (n, ...) payload
+        stack on the device (``fused.cohort_output``), which the idealized
+        backend's SecAgg masks and sums there.
         """
         return None
 

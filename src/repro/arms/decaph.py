@@ -164,7 +164,7 @@ class DeCaPHArm(RoundArm):
         contribs = fused.build_contributions(
             active, stack, losses, cb.sizes, need_payloads
         )
-        return contribs, reduced
+        return contribs, fused.cohort_output(stack, reduced, need_payloads)
 
     def aggregate(
         self,

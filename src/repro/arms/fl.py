@@ -141,7 +141,7 @@ class FLArm(RoundArm):
                 reduced = None
             return fused.build_contributions(
                 active, stack, None, cb.sizes, need_payloads
-            ), reduced
+            ), fused.cohort_output(stack, reduced, need_payloads)
         cb = fused.stack_poisson(
             rng, self.participants, active, self.rate, self.pad,
             steps=self._local_steps(),
@@ -158,7 +158,7 @@ class FLArm(RoundArm):
             (stack,), reduced = self._fused_avg_slim(*args), None
         return fused.build_contributions(
             active, stack, None, cb.sizes, need_payloads
-        ), reduced
+        ), fused.cohort_output(stack, reduced, need_payloads)
 
     def aggregate(
         self,

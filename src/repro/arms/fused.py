@@ -198,9 +198,11 @@ def build_contributions(
     needs per-participant payloads — SecAgg uploads or sim transport — one
     transfer for the whole payload stack; the slices are numpy views).
 
-    With ``need_payloads=False`` the payloads stay on device inside the
-    fused reduced sum and the per-participant ``payload`` is ``None`` — the
-    idealized backend serves the aggregate from the reduced tree instead.
+    With ``need_payloads=False`` the payloads stay on device and the
+    per-participant ``payload`` is ``None`` — the idealized backend serves
+    the aggregate from the reduced tree instead, or, with SecAgg, runs the
+    field arithmetic over the payload stack where it lies
+    (``cohort_output``).
 
     The ``fused_round.fetch`` span covers the wait for the cohort program
     and that one transfer; its ``bytes`` is what came to the host.
@@ -234,3 +236,14 @@ def build_contributions(
         )
         for s, i in enumerate(active)
     }
+
+
+def cohort_output(stack: PyTree, reduced: PyTree | None,
+                  need_payloads: bool) -> PyTree | None:
+    """``fused_round``'s second element: the in-jit reduced aggregate when
+    the program made one; with the payloads withheld and no reduction
+    asked for, the (n, ...) payload stack itself, which the backend then
+    aggregates on the device (SecAgg on the idealized backend)."""
+    if reduced is None and not need_payloads:
+        return stack
+    return reduced

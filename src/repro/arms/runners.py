@@ -48,6 +48,7 @@ from repro.core.secagg import (
     secagg_recovery_bytes,
     secure_sum,
     secure_sum_ints,
+    secure_sum_stack,
 )
 from repro.sim.engine import (
     ComputeDone,
@@ -77,13 +78,18 @@ def default_topology(kind: str, n: int, center: int = 0) -> Topology:
 
 
 class _IdealServices(AggregationServices):
-    """Free, lossless aggregation; SecAgg runs over the raw payload trees."""
+    """Free, lossless aggregation; SecAgg runs over the raw payloads.
+
+    ``fused`` is the fused round-step's device output for the cohort
+    ``cover``: the reduced sum with SecAgg off, the (n, ...) payload stack
+    with it on (``fused.cohort_output``)."""
 
     def __init__(self, cfg, n: int, t: int, secure: bool,
-                 fused_reduced: PyTree | None = None,
+                 fused: PyTree | None = None,
                  cover: frozenset[int] | None = None) -> None:
         self._cfg, self._n, self._t, self._secure = cfg, n, t, secure
-        self.fused_reduced = fused_reduced
+        self.fused_reduced = None if secure else fused
+        self._fused_stack = fused if secure else None
         self._cover = cover
 
     def sum_sizes(self, sizes: Sequence[int]) -> int:
@@ -110,11 +116,14 @@ class _IdealServices(AggregationServices):
                     "idealized SecAgg needs every participant's upload "
                     f"({len(trees)} of {self._n})"
                 )
-            return secure_sum(
-                trees,
-                SecAggConfig(self._n, self._cfg.secagg_frac_bits,
-                             seed=self._cfg.seed + self._t),
-            )
+            cfg = SecAggConfig(self._n, self._cfg.secagg_frac_bits,
+                               seed=self._cfg.seed + self._t)
+            if (self._fused_stack is not None
+                    and set(payloads) == self._cover):
+                # the payload stack never left the device: encode, mask,
+                # sum and decode run there, slot s being participant s
+                return secure_sum_stack(self._fused_stack, cfg)
+            return secure_sum(trees, cfg)
         if any(tr is None for tr in trees):
             raise RuntimeError(
                 "fused round withheld per-participant payloads but the "
@@ -205,17 +214,18 @@ class LocalRunner:
             dst = arm.facilitator(t, active)
             secure = arm.secure_uploads and cfg.use_secagg
             contribs: dict[int, Contribution] | None = None
-            reduced = None
+            fused_out = None
             if cfg.fused_rounds:
-                # one dispatch for the whole cohort; with SecAgg off the
-                # reduced aggregate never leaves the device either
+                # one dispatch for the whole cohort; the payloads never
+                # leave the device: reduced in-jit with SecAgg off, handed
+                # whole to the SecAgg programs with it on
                 with obs.span("fused_round", cat="train", t=t,
                               cohort=len(active)):
                     fr = self._fused_round(arm, params, active, t, rng,
-                                           need_payloads=secure,
+                                           need_payloads=False,
                                            need_reduced=not secure)
                 if fr is not None:
-                    contribs, reduced = fr
+                    contribs, fused_out = fr
             if contribs is None:
                 contribs = {}
                 for i in active:  # ascending index: the arm-contract rng order
@@ -226,11 +236,9 @@ class LocalRunner:
                 if arm.empty_break:
                     break
                 continue
-            services = _IdealServices(
-                cfg, h, t, secure=secure,
-                fused_reduced=None if secure else reduced,
-                cover=frozenset(contribs),
-            )
+            services = _IdealServices(cfg, h, t, secure=secure,
+                                      fused=fused_out,
+                                      cover=frozenset(contribs))
             # SecAgg (when on) runs inside aggregate via the services: the
             # secagg.sizes, secagg.upload and secagg.sum spans, then the
             # arm's update

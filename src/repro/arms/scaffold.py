@@ -158,7 +158,7 @@ class ScaffoldArm(RoundArm):
             (stack, self._ci), reduced = self._fused_step_slim(*args), None
         return fused.build_contributions(
             active, stack, None, cb.sizes, need_payloads
-        ), reduced
+        ), fused.cohort_output(stack, reduced, need_payloads)
 
     def aggregate(
         self,

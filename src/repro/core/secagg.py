@@ -37,6 +37,7 @@ dropout recovery in ``tests/test_secagg_dropout.py``.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Mapping, Sequence
 
@@ -47,13 +48,15 @@ import numpy as np
 import repro.obs as obs
 
 PyTree = Any
+Shapes = tuple[tuple[int, ...], ...]  # a template's leaf shapes
 
 _FIELD_DTYPE = np.uint32
 _FIELD_BITS = 32
 
-# Field arithmetic runs host-side in NumPy: the protocol is a host/network
-# concern (uploads are ciphertexts, not device tensors) and NumPy gives exact
-# 64->32-bit modular arithmetic regardless of jax_enable_x64.
+# Field arithmetic runs on the device, in jitted programs over uint32 arrays
+# (DESIGN.md §7): uint32 adds and sums wrap mod 2^32 exactly, and the
+# fixed-point encode/decode below is exact in float32, so no value needs
+# jax_enable_x64 and a payload the device computed never comes to the host.
 
 
 # Pairs per chunk of the in-jit mask accumulation: bounds resident pad
@@ -74,17 +77,33 @@ class SecAggConfig:
         return float(1 << self.frac_bits)
 
 
-def _encode(x, cfg: SecAggConfig) -> np.ndarray:
-    """float -> field element (two's-complement embedding into uint32)."""
-    q = np.round(np.asarray(x, np.float64) * cfg.scale).astype(np.int64)
-    return (q % (1 << _FIELD_BITS)).astype(_FIELD_DTYPE)
+_TWO_31 = float(1 << (_FIELD_BITS - 1))
+_TWO_32 = float(1 << _FIELD_BITS)
+_MINUS_ONE = (1 << _FIELD_BITS) - 1  # -1 in the field
 
 
-def _decode(v: np.ndarray, cfg: SecAggConfig) -> np.ndarray:
-    """field element -> float (centered: values >= 2^31 are negative)."""
-    v = v.astype(np.int64)
-    v = np.where(v >= (1 << (_FIELD_BITS - 1)), v - (1 << _FIELD_BITS), v)
-    return (v.astype(np.float64) / cfg.scale).astype(np.float32)
+def _field_encode(x: jax.Array, frac_bits: int) -> jax.Array:
+    """float -> field element (two's-complement embedding into uint32),
+    traced.  ``r = round(x * 2^frac_bits)`` is exact in float32 (the scale
+    is a power of two, ``jnp.round`` ties to even like ``np.round``); r
+    below 2^31 in magnitude is its own int32, and a larger r is a multiple
+    of ulp(r) >= 2^8, so ``r mod 2^32``, centred into int32's range, is
+    exact in float32 too.  Bit-identical to the float64 NumPy encode for
+    every finite float32 x whose r fits int64 (``tests/_legacy_secagg.py``
+    keeps that reference)."""
+    r = jnp.round(x.astype(jnp.float32) * float(1 << frac_bits))
+    wrapped = r - _TWO_32 * jnp.floor(r * (1.0 / _TWO_32))  # [0, 2^32)
+    wrapped = jnp.where(wrapped >= _TWO_31, wrapped - _TWO_32, wrapped)
+    centred = jnp.where(jnp.abs(r) < _TWO_31, r, wrapped)
+    return jax.lax.bitcast_convert_type(centred.astype(jnp.int32), jnp.uint32)
+
+
+def _field_decode(v: jax.Array, frac_bits: int) -> jax.Array:
+    """field element -> float32 (centred: values >= 2^31 are negative),
+    traced.  One rounding of v / 2^frac_bits, as the float64 NumPy decode:
+    the power-of-two scale is exact."""
+    signed = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return signed.astype(jnp.float32) * (1.0 / float(1 << frac_bits))
 
 
 # -- vectorized, chunked pair-pad machinery (DESIGN.md §7) -------------------
@@ -103,6 +122,13 @@ def _decode(v: np.ndarray, cfg: SecAggConfig) -> np.ndarray:
 # Z_2^32 is exactly associative/commutative, so chunking changes no bit;
 # the legacy per-leaf loop survives as a reference implementation in
 # ``tests/_legacy_secagg.py`` and aggregates stay bit-identical to it.
+#
+# A pad, a net mask and a ciphertext are lists of per-leaf arrays in the
+# template's leaf shapes (a leading participant or pair axis where there
+# is one), never one flat length-L vector: the TPU tiles an array's last
+# two dimensions, so a flat vector cut into 2-D leaves is a relayout that
+# its compiler unrolls row by row — seconds to minutes of compile and tens
+# to hundreds of MB of code for a 15558 x 1000 leaf.
 
 
 def _pairs(n: int) -> tuple[np.ndarray, np.ndarray]:
@@ -111,26 +137,50 @@ def _pairs(n: int) -> tuple[np.ndarray, np.ndarray]:
     return lo.astype(np.uint32), hi.astype(np.uint32)
 
 
+def _pad_leaves(key: jax.Array, shapes: Shapes) -> list[jax.Array]:
+    """One pad: a uint32 array per leaf shape, leaf li keyed by
+    ``fold_in(key, li)``."""
+    return [
+        jax.random.bits(jax.random.fold_in(key, li), shape, dtype=jnp.uint32)
+        for li, shape in enumerate(shapes)
+    ]
+
+
+def _leading(v: jax.Array, ndim: int) -> jax.Array:
+    """A per-row vector shaped to broadcast over rows of rank ``ndim``."""
+    return v.reshape((-1,) + (1,) * (ndim - 1))
+
+
+def _scatter_signed(masks, pads, lo_c, hi_c, v_c):
+    """Add each pad row to its lo participant's net mask and subtract it
+    from its hi participant's (uint32: exact two's complement); padding
+    rows (``v_c`` 0) add nothing."""
+    out = []
+    for m, p in zip(masks, pads):
+        p = p * _leading(v_c, p.ndim)
+        out.append(m.at[lo_c].add(p).at[hi_c].add(-p))
+    return out
+
+
 @partial(jax.jit, static_argnums=(4, 5))
 def _pair_mask_scan(
     base_key: jax.Array, los: jax.Array, his: jax.Array, valid: jax.Array,
-    n: int, length: int,
-) -> jax.Array:
-    """(n, L) signed net masks from (n_chunks, C) pair-index chunks."""
+    n: int, shapes: Shapes,
+) -> list[jax.Array]:
+    """Per-leaf (n, ...) signed net masks from (n_chunks, C) pair-index
+    chunks."""
 
     def body(masks, inp):
         lo_c, hi_c, v_c = inp
 
         def one(lo, hi):
             k = jax.random.fold_in(jax.random.fold_in(base_key, lo), hi)
-            return jax.random.bits(k, (length,), dtype=jnp.uint32)
+            return _pad_leaves(k, shapes)
 
-        pads = jax.vmap(one)(lo_c, hi_c) * v_c[:, None]  # pad rows -> 0
-        masks = masks.at[lo_c].add(pads)
-        masks = masks.at[hi_c].add(-pads)  # uint32: exact two's complement
-        return masks, None
+        pads = jax.vmap(one)(lo_c, hi_c)
+        return _scatter_signed(masks, pads, lo_c, hi_c, v_c), None
 
-    masks0 = jnp.zeros((n, length), jnp.uint32)
+    masks0 = [jnp.zeros((n,) + shape, jnp.uint32) for shape in shapes]
     masks, _ = jax.lax.scan(body, masks0, (los, his, valid))
     return masks
 
@@ -138,8 +188,8 @@ def _pair_mask_scan(
 _SEED_PAD_KEY = jax.random.key(0x5ECA66)
 
 
-def _seed_pad_row(hi, lo, length: int):
-    """One pad row from a DH agreement split into 32-bit words.
+def _seed_pads(hi, lo, shapes: Shapes) -> list[jax.Array]:
+    """One pad from a DH agreement split into 32-bit words.
 
     This is THE derivation: the masking scan and the dropout-recovery path
     must produce bit-identical pads from the same seed words, or survivors'
@@ -147,29 +197,25 @@ def _seed_pad_row(hi, lo, length: int):
     through this one function.
     """
     k = jax.random.fold_in(jax.random.fold_in(_SEED_PAD_KEY, hi), lo)
-    return jax.random.bits(k, (length,), dtype=jnp.uint32)
+    return _pad_leaves(k, shapes)
 
 
 @partial(jax.jit, static_argnums=(5, 6))
 def _seed_mask_scan(
     hi_words: jax.Array, lo_words: jax.Array,
     los: jax.Array, his: jax.Array, valid: jax.Array,
-    n: int, length: int,
-) -> jax.Array:
-    """(n, L) signed net masks from chunked DH-seed words (the seed, not
-    the pair indices, keys the PRG — so a pad can be regenerated from a
-    Shamir-reconstructed secret during recovery)."""
+    n: int, shapes: Shapes,
+) -> list[jax.Array]:
+    """Per-leaf (n, ...) signed net masks from chunked DH-seed words (the
+    seed, not the pair indices, keys the PRG — so a pad can be regenerated
+    from a Shamir-reconstructed secret during recovery)."""
 
     def body(masks, inp):
         hw_c, lw_c, lo_c, hi_c, v_c = inp
-        pads = jax.vmap(
-            lambda hi, lo: _seed_pad_row(hi, lo, length)
-        )(hw_c, lw_c) * v_c[:, None]
-        masks = masks.at[lo_c].add(pads)
-        masks = masks.at[hi_c].add(-pads)
-        return masks, None
+        pads = jax.vmap(lambda hi, lo: _seed_pads(hi, lo, shapes))(hw_c, lw_c)
+        return _scatter_signed(masks, pads, lo_c, hi_c, v_c), None
 
-    masks0 = jnp.zeros((n, length), jnp.uint32)
+    masks0 = [jnp.zeros((n,) + shape, jnp.uint32) for shape in shapes]
     masks, _ = jax.lax.scan(
         body, masks0, (hi_words, lo_words, los, his, valid)
     )
@@ -195,28 +241,26 @@ def _chunked(arrs: Sequence[np.ndarray], chunk: int) -> list[np.ndarray]:
 
 def _signed_masks(
     n: int,
-    length: int,
+    shapes: Shapes,
     los: np.ndarray,
     his: np.ndarray,
     *,
     chunk: int = _DEFAULT_PAD_CHUNK,
     base_key: jax.Array | None = None,
     seeds: Sequence[int] | None = None,
-) -> np.ndarray:
-    """(n, L) net masks: row i = sum_{i=lo} pad - sum_{i=hi} pad (mod 2^32),
-    accumulated in-jit over ``chunk``-pair slices; exactly one of
-    ``base_key`` (pair-index keyed pads) / ``seeds`` (DH-agreement keyed
-    pads) selects the PRG family."""
+) -> list[jax.Array]:
+    """Per-leaf (n, ...) net masks on the device: row i = sum_{i=lo} pad -
+    sum_{i=hi} pad (mod 2^32), accumulated in-jit over ``chunk``-pair
+    slices; exactly one of ``base_key`` (pair-index keyed pads) / ``seeds``
+    (DH-agreement keyed pads) selects the PRG family."""
     if len(los) == 0:
-        return np.zeros((n, length), _FIELD_DTYPE)
+        return [jnp.zeros((n,) + shape, jnp.uint32) for shape in shapes]
     if seeds is not None:
         hi_w, lo_w = _seed_words(seeds)
         hw, lw, lo_c, hi_c, valid = _chunked([hi_w, lo_w, los, his], chunk)
-        out = _seed_mask_scan(hw, lw, lo_c, hi_c, valid, n, length)
-    else:
-        lo_c, hi_c, valid = _chunked([los, his], chunk)
-        out = _pair_mask_scan(base_key, lo_c, hi_c, valid, n, length)
-    return np.asarray(out)
+        return _seed_mask_scan(hw, lw, lo_c, hi_c, valid, n, shapes)
+    lo_c, hi_c, valid = _chunked([los, his], chunk)
+    return _pair_mask_scan(base_key, lo_c, hi_c, valid, n, shapes)
 
 
 def _seed_words(seeds: Sequence[int]) -> tuple[np.ndarray, np.ndarray]:
@@ -228,125 +272,136 @@ def _seed_words(seeds: Sequence[int]) -> tuple[np.ndarray, np.ndarray]:
 
 @partial(jax.jit, static_argnums=(2,))
 def _batched_seed_pads(
-    hi_words: jax.Array, lo_words: jax.Array, length: int
-) -> jax.Array:
-    """(n_seeds, length) pads from DH-seed words — the *recovery* path,
-    where the handful of survivor-side pads of a dropped party really is
-    needed as a matrix (to cancel them from the ciphertext sum).  Same
-    ``_seed_pad_row`` derivation as the masking scan, by construction."""
+    hi_words: jax.Array, lo_words: jax.Array, shapes: Shapes
+) -> list[jax.Array]:
+    """Per-leaf (n_seeds, ...) pads from DH-seed words — the *recovery*
+    path, where the handful of survivor-side pads of a dropped party really
+    is needed (to cancel them from the ciphertext sum).  Same
+    ``_seed_pads`` derivation as the masking scan, by construction."""
     return jax.vmap(
-        lambda hi, lo: _seed_pad_row(hi, lo, length)
+        lambda hi, lo: _seed_pads(hi, lo, shapes)
     )(hi_words, lo_words)
 
 
-def _flatten_encoded(
-    leaves: Sequence[Any], template: Sequence[Any], cfg: SecAggConfig
-) -> np.ndarray:
-    """Encode every leaf and concatenate into one flat field vector."""
-    out = []
-    for li, (x, tmpl) in enumerate(zip(leaves, template)):
-        shape = tuple(np.shape(tmpl))
-        if tuple(np.shape(x)) != shape:
-            raise ValueError(f"leaf {li} shape {np.shape(x)} != {shape}")
-        out.append(_encode(x, cfg).ravel())
-    return np.concatenate(out) if out else np.zeros((0,), _FIELD_DTYPE)
+# -- the field programs ------------------------------------------------------
+#
+# A cohort's uploads are per-leaf (n, ...) uint32 ciphertext stacks: row s
+# of every leaf is the s-th uploading participant's payload leaf encoded
+# into the field plus that participant's net mask.  The aggregator sums each leaf
+# over the participant axis (the masks cancel), adds back any recovered
+# pads and decodes.  Two entry points each: the payload stack as the fused
+# cohort program leaves it (``secure_sum_stack``), and per-participant
+# trees and ciphertext lists, stacked and split inside the same program
+# (``upload``/``upload_all``/``aggregate``, the form the sim transport
+# ships).  Every one is plain ``jax.jit``: the fused round's O(1)-dispatch
+# count (``repro.instrument``) covers the cohort program only.
 
 
-def _encode_cohort(
-    trees: Sequence[PyTree], template: Sequence[Any], cfg: SecAggConfig
-) -> np.ndarray:
-    """(n, L) encoded field matrix for a whole cohort of payload trees.
-
-    ONE ``jax.device_get`` moves every participant's (possibly
-    device-resident) payload leaves to the host together, instead of the
-    per-silo implicit transfers the per-upload ``np.asarray`` path paid;
-    the fixed-point encode is elementwise, so batching changes no bit.
-    """
-    cohort_leaves = [jax.tree_util.tree_leaves(v) for v in trees]
-    for leaves in cohort_leaves:
-        if len(leaves) != len(template):
-            raise ValueError("pytree structure mismatch")
-    with obs.span("secagg.encode", cat="secagg"):
-        cohort_leaves = jax.device_get(cohort_leaves)
-        return np.stack([
-            _flatten_encoded(leaves, template, cfg)
-            for leaves in cohort_leaves
-        ]) if cohort_leaves else np.zeros((0, 0), _FIELD_DTYPE)
+@partial(jax.jit, static_argnums=(2,))
+def _cohort_ciphertexts(
+    stacks: Sequence[jax.Array], masks: Sequence[jax.Array], frac_bits: int,
+) -> list[jax.Array]:
+    """Per-leaf (n, ...) ciphertext stacks from (n, ...) payload stacks and
+    the same rows' per-leaf net masks."""
+    return [_field_encode(x, frac_bits) + m  # uint32: wraps mod 2^32
+            for x, m in zip(stacks, masks)]
 
 
-def _split_flat(flat: np.ndarray, template: Sequence[Any]) -> list[np.ndarray]:
-    """Inverse of ``_flatten_encoded``: flat vector -> per-leaf arrays."""
-    out, off = [], 0
-    for leaf in template:
-        shape = tuple(np.shape(leaf))
-        # np.prod(()) == 1, so scalars count 1 and empty leaves count 0 —
-        # matching exactly what _flatten_encoded ravels
-        size = int(np.prod(shape))
-        out.append(flat[off:off + size].reshape(shape))
-        off += size
-    return out
+@partial(jax.jit, static_argnums=(2,))
+def _participant_ciphertexts(
+    cohort: Sequence[Sequence[jax.Array]], masks: Sequence[jax.Array],
+    frac_bits: int,
+) -> list[list[jax.Array]]:
+    """Per-participant ciphertexts (uint32 leaf lists) from per-participant
+    payload leaves: ``_cohort_ciphertexts`` between a stack and a split."""
+    stacks = [jnp.stack(col) for col in zip(*cohort)]
+    cts = _cohort_ciphertexts(stacks, masks, frac_bits)
+    return [[c[s] for c in cts] for s in range(len(cohort))]
 
 
-def _stack_ciphertexts(
-    uploads: Sequence[list[np.ndarray]],
-) -> np.ndarray:
-    """(n_uploads, L) field matrix from per-leaf ciphertext lists."""
-    return np.stack([
-        np.concatenate([np.asarray(u).ravel() for u in up])
-        for up in uploads
-    ])
+def _sum_decode(ciphertexts, recovered, frac_bits: int) -> list[jax.Array]:
+    """Field sum of each leaf's (m, ...) ciphertext rows, plus the signed
+    recovered pads ``(pads, signs)`` if any, decoded to float32."""
+    totals = [jnp.sum(c, axis=0, dtype=jnp.uint32) for c in ciphertexts]
+    if recovered is not None:
+        pads, signs = recovered  # signs: 1 or 2^32 - 1 (= -1 in the field)
+        totals = [t + jnp.sum(p * _leading(signs, p.ndim), axis=0,
+                              dtype=jnp.uint32)
+                  for t, p in zip(totals, pads)]
+    return [_field_decode(t, frac_bits) for t in totals]
+
+
+_field_sum = jax.jit(_sum_decode, static_argnums=(2,))
+
+
+@partial(jax.jit, static_argnums=(2,))
+def _participant_field_sum(uploads, recovered, frac_bits: int):
+    """``_field_sum`` over per-participant ciphertext lists, stacked into
+    per-leaf rows inside the program."""
+    return _sum_decode([jnp.stack(col) for col in zip(*uploads)],
+                       recovered, frac_bits)
+
+
+def _masked_uploads(session, program, payloads, rows: Sequence[int]):
+    """Run a ciphertext ``program`` over ``payloads`` for the ascending
+    participants ``rows``, under the ``secagg.upload`` span (the mask
+    program's and the encode program's dispatches)."""
+    with obs.span("secagg.upload", cat="secagg", participants=len(rows),
+                  length=session._length):
+        masks = session._net_masks()
+        if len(rows) != session.cfg.n_participants:  # a subset's rows
+            masks = [m[np.asarray(rows)] for m in masks]
+        with obs.span("secagg.encode", cat="secagg"):
+            return program(payloads, masks, session.cfg.frac_bits)
 
 
 def _masked_cohort_uploads(
     session, values: Mapping[int, PyTree]
-) -> dict[int, list[np.ndarray]]:
-    """Shared ``upload_all`` body: one batched host transfer for the whole
-    cohort's payloads + one vectorized masking pass.  Bit-identical to
-    per-participant ``upload`` calls (encode is elementwise, masks are the
-    same rows)."""
+) -> dict[int, list[jax.Array]]:
+    """Shared ``upload``/``upload_all`` body: every participant's
+    ciphertext from one encode-and-mask program on the device."""
     if not values:
         return {}
     order = sorted(values)
-    with obs.span("secagg.upload", cat="secagg", participants=len(order),
-                  length=session._length):
-        enc = _encode_cohort(
-            [values[i] for i in order], session._leaves, session.cfg
-        )
-        with np.errstate(over="ignore"):  # modular field arithmetic
-            enc = enc + session._flat_masks()[np.asarray(order, np.intp)]
-        return {
-            i: _split_flat(row, session._leaves)
-            for i, row in zip(order, enc)
-        }
+    cohort = [jax.tree_util.tree_leaves(values[i]) for i in order]
+    _check_leaves(cohort, session._shapes, "payload")
+    uploads = _masked_uploads(session, _participant_ciphertexts, cohort,
+                              order)
+    return dict(zip(order, uploads))
 
 
-def _session_masks(session, **prg) -> np.ndarray:
-    """The session's (n, L) net-mask matrix under a ``secagg.masks`` span:
-    the pad program on the device and the fetch of its result.  ``prg`` is
-    ``_signed_masks``'s ``base_key`` or ``seeds``."""
+def _session_masks(session, **prg) -> list[jax.Array]:
+    """The session's per-leaf (n, ...) net masks under a ``secagg.masks``
+    span: the pad program's dispatch; the masks stay on the device.
+    ``prg`` is ``_signed_masks``'s ``base_key`` or ``seeds``."""
     t0 = obs.now()
     masks = _signed_masks(
-        session.cfg.n_participants, session._length,
+        session.cfg.n_participants, session._shapes,
         session._los, session._his,
         chunk=session.cfg.pad_chunk_pairs, **prg,
     )
     if t0 is not None:
         obs.complete("secagg.masks", t0, cat="secagg",
-                     pairs=len(session._los), bytes=masks.nbytes)
+                     pairs=len(session._los), bytes=0)
     return masks
 
 
-def _decoded_tree(session, total: np.ndarray, t0: float | None) -> PyTree:
-    """Decode the field sum back onto the device as the template's pytree,
-    and close the ``secagg.sum`` span opened at ``t0``."""
-    decoded = [
-        jnp.asarray(_decode(t, session.cfg))
-        for t in _split_flat(total, session._leaves)
-    ]
+def _decoded_tree(session, program, ciphertexts, recovered,
+                  t0: float | None) -> PyTree:
+    """Run a field-sum ``program`` (sum, recovered pads, decode) and close
+    the ``secagg.sum`` span opened at ``t0``; the decoded leaves stay on
+    the device, in the template's structure."""
+    leaves = program(ciphertexts, recovered, session.cfg.frac_bits)
     if t0 is not None:
-        obs.complete("secagg.sum", t0, cat="secagg",
-                     bytes=sum(x.nbytes for x in decoded))
-    return jax.tree_util.tree_unflatten(session._treedef, decoded)
+        obs.complete("secagg.sum", t0, cat="secagg", bytes=0)
+    return jax.tree_util.tree_unflatten(session._treedef, leaves)
+
+
+def _template_layout(session, template: PyTree) -> None:
+    """The template's structure, leaf shapes and field length."""
+    leaves, session._treedef = jax.tree_util.tree_flatten(template)
+    session._shapes = tuple(tuple(np.shape(x)) for x in leaves)
+    session._length = sum(math.prod(s) for s in session._shapes)
 
 
 class SecAggSession:
@@ -355,15 +410,12 @@ class SecAggSession:
     def __init__(self, cfg: SecAggConfig, template: PyTree):
         self.cfg = cfg
         self.template = template
-        self._leaves, self._treedef = jax.tree_util.tree_flatten(template)
-        self._length = int(sum(
-            np.prod(np.shape(x)) for x in self._leaves
-        ))
+        _template_layout(self, template)
         self._base_key = jax.random.key(cfg.seed)
         self._los, self._his = _pairs(cfg.n_participants)
-        self._masks: np.ndarray | None = None  # (n, L), built lazily
+        self._masks: list[jax.Array] | None = None  # built lazily
 
-    def _flat_masks(self) -> np.ndarray:
+    def _net_masks(self) -> list[jax.Array]:
         """Every participant's net mask, accumulated over pair chunks."""
         if self._masks is None:
             self._masks = _session_masks(self, base_key=self._base_key)
@@ -371,26 +423,20 @@ class SecAggSession:
 
     def mask_for(self, i: int) -> list[np.ndarray]:
         """Net mask participant i applies (sums to zero over participants)."""
-        return _split_flat(self._flat_masks()[i], self._leaves)
+        return [np.asarray(m)[i] for m in self._net_masks()]
 
-    def upload(self, i: int, values: PyTree) -> list[np.ndarray]:
+    def upload(self, i: int, values: PyTree) -> list[jax.Array]:
         """Masked ciphertext participant i sends to the leader."""
-        leaves = jax.tree_util.tree_leaves(values)
-        if len(leaves) != len(self._leaves):
-            raise ValueError("pytree structure mismatch")
-        with np.errstate(over="ignore"):  # modular wraparound is the protocol
-            flat = _flatten_encoded(leaves, self._leaves, self.cfg)
-            flat = flat + self._flat_masks()[i]
-        return _split_flat(flat, self._leaves)
+        return _masked_cohort_uploads(self, {i: values})[i]
 
     def upload_all(
         self, values: Mapping[int, PyTree]
-    ) -> dict[int, list[np.ndarray]]:
-        """Ciphertexts for a whole cohort: one host transfer, one masking
-        pass (participant index -> masked ciphertext)."""
+    ) -> dict[int, list[jax.Array]]:
+        """Ciphertexts for a whole cohort: one masking program on the
+        device (participant index -> masked ciphertext)."""
         return _masked_cohort_uploads(self, values)
 
-    def aggregate(self, uploads: Sequence[list[np.ndarray]]) -> PyTree:
+    def aggregate(self, uploads: Sequence[list[jax.Array]]) -> PyTree:
         """Leader-side sum of ciphertexts; masks cancel exactly in Z_2^32."""
         if len(uploads) != self.cfg.n_participants:
             raise ValueError(
@@ -400,29 +446,27 @@ class SecAggSession:
                 "DropoutRobustSession if participants may drop out"
             )
         t0 = obs.now()
-        _check_uploads(uploads, self._leaves)
-        with np.errstate(over="ignore"):  # modular wraparound is the protocol
-            total = _stack_ciphertexts(uploads).sum(
-                axis=0, dtype=_FIELD_DTYPE
-            )
-        return _decoded_tree(self, total, t0)
+        _check_leaves(uploads, self._shapes, "upload")
+        return _decoded_tree(self, _participant_field_sum, list(uploads),
+                             None, t0)
 
 
-def _check_uploads(
-    uploads: Sequence[list[np.ndarray]], leaves: Sequence[Any]
+def _check_leaves(
+    rows: Sequence[Sequence[Any]], shapes: Shapes, what: str
 ) -> None:
-    """Fail loudly on short/misshapen ciphertexts (silent-garbage guard)."""
-    for k, up in enumerate(uploads):
-        if len(up) != len(leaves):
+    """Fail loudly on short/misshapen payloads or ciphertexts (the
+    silent-garbage guard): each row's leaves must be the template's."""
+    for k, row in enumerate(rows):
+        if len(row) != len(shapes):
             raise ValueError(
-                f"upload {k} has {len(up)} leaves, template has "
-                f"{len(leaves)} — truncated or mis-structured ciphertext"
+                f"{what} {k} has {len(row)} leaves, template has "
+                f"{len(shapes)} — truncated or mis-structured {what}"
             )
-        for li, (u, leaf) in enumerate(zip(up, leaves)):
-            if tuple(np.shape(u)) != tuple(np.shape(leaf)):
+        for li, (u, shape) in enumerate(zip(row, shapes)):
+            if tuple(np.shape(u)) != shape:
                 raise ValueError(
-                    f"upload {k} leaf {li} shape {np.shape(u)} != template "
-                    f"shape {np.shape(leaf)}"
+                    f"{what} {k} leaf {li} shape {np.shape(u)} != template "
+                    f"shape {shape}"
                 )
 
 
@@ -440,6 +484,26 @@ def secure_sum(values: Sequence[PyTree], cfg: SecAggConfig) -> PyTree:
     session = SecAggSession(cfg, values[0])
     uploads = session.upload_all(dict(enumerate(values)))
     return session.aggregate([uploads[i] for i in range(len(values))])
+
+
+def secure_sum_stack(stack: PyTree, cfg: SecAggConfig) -> PyTree:
+    """``secure_sum`` over a cohort's payloads held as one (n, ...) stack
+    per leaf, row s participant s's — the fused cohort program's output,
+    masked, summed and decoded where it lies: the uploads are per-leaf
+    (n, ...) ciphertext stacks on the device."""
+    leaves, treedef = jax.tree_util.tree_flatten(stack)
+    n = cfg.n_participants
+    if not leaves or any(np.shape(x)[:1] != (n,) for x in leaves):
+        raise ValueError(
+            f"secure_sum_stack: every leaf needs a leading axis of the "
+            f"{n} participants' rows (dropouts need DropoutRobustSession)"
+        )
+    session = SecAggSession(cfg, jax.tree_util.tree_unflatten(treedef, [
+        jax.ShapeDtypeStruct(np.shape(x)[1:], x.dtype) for x in leaves
+    ]))
+    ciphertexts = _masked_uploads(session, _cohort_ciphertexts, leaves,
+                                  range(n))
+    return _decoded_tree(session, _field_sum, ciphertexts, None, obs.now())
 
 
 def secure_sum_ints(values: Sequence[int], *, n_participants: int,
@@ -465,8 +529,9 @@ def secure_sum_ints(values: Sequence[int], *, n_participants: int,
         raise ValueError("secure_sum_ints: total overflows the field")
     base_key = jax.random.key(seed)
     los, his = _pairs(n_participants)
-    masks = _signed_masks(n_participants, 1, los, his,
-                          base_key=base_key)[:, 0]
+    (masks,) = _signed_masks(n_participants, ((1,),), los, his,
+                             base_key=base_key)
+    masks = np.asarray(masks)[:, 0]
     with np.errstate(over="ignore"):  # modular field arithmetic
         ciphertexts = np.asarray(values, np.uint64).astype(_FIELD_DTYPE) + masks
         total = int(ciphertexts.sum(dtype=_FIELD_DTYPE))
@@ -563,12 +628,9 @@ class DropoutRobustSession:
         if not 2 <= self.threshold <= n:
             raise ValueError(f"threshold {self.threshold} not in [2, {n}]")
         self.template = template
-        self._leaves, self._treedef = jax.tree_util.tree_flatten(template)
-        self._length = int(sum(
-            np.prod(np.shape(x)) for x in self._leaves
-        ))
+        _template_layout(self, template)
         self._los, self._his = _pairs(n)
-        self._masks: np.ndarray | None = None  # (n, L), built lazily
+        self._masks: list[jax.Array] | None = None  # built lazily
         # Each participant's local randomness (one stream per party would be
         # the deployment picture; a single seeded stream keeps tests exact).
         rng = np.random.default_rng(np.uint64(cfg.seed) ^ np.uint64(0x5ECA66))
@@ -591,14 +653,7 @@ class DropoutRobustSession:
             self.public_keys[other], self._secret_keys[holder], _SHAMIR_PRIME
         )
 
-    def _pads_from_seeds(self, seeds: Sequence[int]) -> np.ndarray:
-        """(len(seeds), L) pads from DH agreements, one batched PRG call."""
-        if not seeds:
-            return np.zeros((0, self._length), _FIELD_DTYPE)
-        hi, lo = _seed_words(seeds)
-        return np.asarray(_batched_seed_pads(hi, lo, self._length))
-
-    def _flat_masks(self) -> np.ndarray:
+    def _net_masks(self) -> list[jax.Array]:
         """Every participant's net mask; each pair's pad generated once,
         accumulated over pair chunks (never the full pad matrix)."""
         if self._masks is None:
@@ -609,21 +664,15 @@ class DropoutRobustSession:
             self._masks = _session_masks(self, seeds=seeds)
         return self._masks
 
-    def upload(self, i: int, values: PyTree) -> list[np.ndarray]:
+    def upload(self, i: int, values: PyTree) -> list[jax.Array]:
         """Masked ciphertext from participant i (pads vs. every peer)."""
-        leaves = jax.tree_util.tree_leaves(values)
-        if len(leaves) != len(self._leaves):
-            raise ValueError("pytree structure mismatch")
-        with np.errstate(over="ignore"):  # modular field arithmetic
-            flat = _flatten_encoded(leaves, self._leaves, self.cfg)
-            flat = flat + self._flat_masks()[i]
-        return _split_flat(flat, self._leaves)
+        return _masked_cohort_uploads(self, {i: values})[i]
 
     def upload_all(
         self, values: Mapping[int, PyTree]
-    ) -> dict[int, list[np.ndarray]]:
-        """Ciphertexts for a whole cohort: one host transfer, one masking
-        pass (slot index -> masked ciphertext)."""
+    ) -> dict[int, list[jax.Array]]:
+        """Ciphertexts for a whole cohort: one masking program on the
+        device (slot index -> masked ciphertext)."""
         return _masked_cohort_uploads(self, values)
 
     # -- recovery -----------------------------------------------------------
@@ -635,7 +684,7 @@ class DropoutRobustSession:
         return [self._shares[dropped][j] for j in survivors]
 
     def aggregate(
-        self, uploads: dict[int, list[np.ndarray]]
+        self, uploads: dict[int, list[jax.Array]]
     ) -> PyTree:
         """Sum received ciphertexts; reconstruct + cancel dropped pads.
 
@@ -654,27 +703,27 @@ class DropoutRobustSession:
                 f"{self.threshold}: cannot reconstruct dropped masks"
             )
         t0 = obs.now()
-        _check_uploads([uploads[s] for s in survivors], self._leaves)
-        with np.errstate(over="ignore"):
-            total = _stack_ciphertexts(
-                [uploads[s] for s in survivors]
-            ).sum(axis=0, dtype=_FIELD_DTYPE)
+        received = [uploads[s] for s in survivors]
+        _check_leaves(received, self._shapes, "upload")
+        recovered = None
+        if dropped:
+            seeds, signs = [], []
             for d in dropped:
                 # Any `threshold` survivors' shares reconstruct u_d exactly.
                 shares = self.recovery_shares(d, survivors[: self.threshold])
                 u_d = shamir_reconstruct(shares)
                 # Regenerate every survivor-side pad involving d from the
-                # reconstructed secret (one batched PRG call per dropped
-                # party) and cancel: survivor j applied +pad if j < d else
-                # -pad, so subtract for j < d and add back for j > d.
-                pads = self._pads_from_seeds([
-                    pow(self.public_keys[j], u_d, _SHAMIR_PRIME)
-                    for j in survivors
-                ])
-                before = np.asarray([j < d for j in survivors])
-                total = total - pads[before].sum(axis=0, dtype=_FIELD_DTYPE)
-                total = total + pads[~before].sum(axis=0, dtype=_FIELD_DTYPE)
-        return _decoded_tree(self, total, t0)
+                # reconstructed secret and cancel it: survivor j applied
+                # +pad if j < d else -pad, so subtract for j < d and add
+                # back for j > d.
+                seeds += [pow(self.public_keys[j], u_d, _SHAMIR_PRIME)
+                          for j in survivors]
+                signs += [_MINUS_ONE if j < d else 1 for j in survivors]
+            # one batched PRG call for every dropped party's pads
+            pads = _batched_seed_pads(*_seed_words(seeds), self._shapes)
+            recovered = (pads, np.asarray(signs, np.uint32))
+        return _decoded_tree(self, _participant_field_sum, received,
+                             recovered, t0)
 
 
 def secure_sum_with_dropouts(

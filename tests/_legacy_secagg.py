@@ -8,8 +8,11 @@ calls).  Kept verbatim as the reference the vectorized path is tested
 against: mask cancellation is exact in the field, so the *aggregates* must
 be bit-identical even though the pad values themselves differ.
 
-Only the session internals are vendored; the field encoding and the Shamir
-algebra are unchanged in the live module and imported from it.
+Only the session internals are vendored, with the float64 NumPy field
+encoding they used (``_encode``/``_decode``): the live module encodes on
+the device in float32, and these stay the reference it is tested against
+bit for bit.  The Shamir algebra is unchanged in the live module and
+imported from it.
 """
 
 from __future__ import annotations
@@ -22,9 +25,8 @@ import numpy as np
 
 from repro.core.secagg import (
     SecAggConfig,
+    _FIELD_BITS,
     _FIELD_DTYPE,
-    _decode,
-    _encode,
     _SHAMIR_PRIME,
     _DH_GENERATOR,
     shamir_share,
@@ -32,6 +34,19 @@ from repro.core.secagg import (
 )
 
 PyTree = Any
+
+
+def _encode(x, cfg: SecAggConfig) -> np.ndarray:
+    """float -> field element (two's-complement embedding into uint32)."""
+    q = np.round(np.asarray(x, np.float64) * cfg.scale).astype(np.int64)
+    return (q % (1 << _FIELD_BITS)).astype(_FIELD_DTYPE)
+
+
+def _decode(v: np.ndarray, cfg: SecAggConfig) -> np.ndarray:
+    """field element -> float (centered: values >= 2^31 are negative)."""
+    v = v.astype(np.int64)
+    v = np.where(v >= (1 << (_FIELD_BITS - 1)), v - (1 << _FIELD_BITS), v)
+    return (v.astype(np.float64) / cfg.scale).astype(np.float32)
 
 
 def _pair_key(base: jax.Array, i: int, j: int) -> jax.Array:

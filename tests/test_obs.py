@@ -31,7 +31,13 @@ from repro.obs.recorder import EventStreamError, Recorder, validate_events
 from repro.sim import nodes_from_trace
 from repro.sim.nodes import heterogeneous_trace
 
-from test_arms_equivalence import _cfg, _make_model, _silos
+from test_arms_equivalence import (
+    _cfg,
+    _ideal_nodes,
+    _ideal_topology,
+    _make_model,
+    _silos,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -344,7 +350,9 @@ def _span_count(rec, name):
 
 def test_secagg_round_spans_split_the_aggregate():
     """Each span of the round's split occurs once per round, nested under
-    the span that causes it, and its ``bytes`` are the arrays' own."""
+    the span that causes it, and its ``bytes`` are what crosses between
+    host and device: the losses only, SecAgg's field arithmetic runs on
+    the device."""
     rounds, h = 2, 4
     model = _make_model(5)
     with obs.recording() as rec:
@@ -365,18 +373,43 @@ def test_secagg_round_spans_split_the_aggregate():
     leaves = jax.tree_util.tree_leaves(model.init_fn(jax.random.key(0)))
     length = sum(np.asarray(x).size for x in leaves)
     f32 = np.dtype(np.float32).itemsize
-    masks = np.zeros((h, length), np.uint32)   # one field row each
     for e in _spans(rec, "secagg.upload"):
         assert e["args"] == {"participants": h, "length": length}
+    # the net masks stay on the device
     for e in _spans(rec, "secagg.masks"):
-        assert e["args"] == {"pairs": h * (h - 1) // 2,
-                             "bytes": masks.nbytes}
-    # the payload stack and the losses come to the host
+        assert e["args"] == {"pairs": h * (h - 1) // 2, "bytes": 0}
+    # only the losses come to the host; the payload stack stays put
     assert [e["args"]["bytes"] for e in _spans(rec, "fused_round.fetch")] \
-        == [h * length * f32 + h * f32] * rounds
-    # the decoded float32 sum goes back to the device
+        == [h * f32] * rounds
+    # the field sum is decoded on the device
     assert [e["args"]["bytes"] for e in _spans(rec, "secagg.sum")] \
-        == [length * f32] * rounds
+        == [0] * rounds
+
+
+@pytest.mark.parametrize("h", [2, 5, 8])
+def test_ideal_secagg_keeps_the_payload_stack_on_device(h):
+    """With SecAgg on, the idealized backend hands the cohort's payload
+    stack to the field programs where the cohort program left it: only the
+    losses are fetched, neither masks nor sums cross, and the parameters
+    after 3 rounds are the sim backend's (per-participant ciphertexts,
+    dropout-robust session) bit for bit."""
+    silos = _silos(sizes=(120,) * h)
+    model = _make_model(5)
+    cfg = _cfg(rounds=3, use_secagg=True)
+    with obs.recording() as rec:
+        ideal = arms.run("decaph", model, silos, cfg)
+    simmed = arms.run("decaph", model, silos, cfg, backend="sim",
+                      nodes=_ideal_nodes(h), topo=_ideal_topology("full", h))
+    assert ideal.rounds_completed == simmed.rounds_completed == 3
+    f32 = np.dtype(np.float32).itemsize
+    assert [e["args"]["bytes"] for e in _spans(rec, "fused_round.fetch")] \
+        == [h * f32] * 3
+    for name in ("secagg.masks", "secagg.sum"):
+        assert [e["args"]["bytes"] for e in _spans(rec, name)] == [0] * 3
+    for a, b in zip(jax.tree_util.tree_leaves(ideal.params),
+                    jax.tree_util.tree_leaves(simmed.params)):
+        assert isinstance(a, jax.Array)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_no_secagg_span_with_secagg_off():

@@ -5,8 +5,10 @@ sign-convention scatter) changes every pad *value* but not a single
 aggregate *bit*: mask cancellation is exact in Z_2^32 either way, so the
 sum of uploads equals the sum of encoded plaintexts exactly in both
 implementations.  These tests pin that contract against the vendored
-pre-refactor loops in ``tests/_legacy_secagg.py``, plus field round-trip
-properties for ``_encode``/``_decode`` and the exact integer sum path.
+pre-refactor loops in ``tests/_legacy_secagg.py``, the device field
+encode/decode bit for bit against that file's float64 NumPy
+``_encode``/``_decode``, field round-trip properties, and the exact
+integer sum path.
 """
 
 import jax
@@ -21,16 +23,28 @@ from repro.core.secagg import (
     SecAggSession,
     secure_sum,
     secure_sum_ints,
+    secure_sum_stack,
     secure_sum_with_dropouts,
-    _decode,
-    _encode,
+    _field_decode,
+    _field_encode,
 )
 
 from _legacy_secagg import (
     LegacySecAggSession,
+    _decode,
+    _encode,
     legacy_secure_sum,
     legacy_secure_sum_with_dropouts,
 )
+
+_device_encode = jax.jit(_field_encode, static_argnums=(1,))
+_device_decode = jax.jit(_field_decode, static_argnums=(1,))
+
+
+def _round_trip(x, cfg):
+    """decode(encode(x)) through the device programs' field arithmetic."""
+    enc = _device_encode(jnp.asarray(x, jnp.float32), cfg.frac_bits)
+    return np.asarray(_device_decode(enc, cfg.frac_bits))
 
 
 def _trees(rng, n, dims=(7, 3)):
@@ -68,6 +82,23 @@ def test_secure_sum_bit_identical_to_legacy_loop(n, seed):
     for a, b in zip(jax.tree_util.tree_leaves(new),
                     jax.tree_util.tree_leaves(old)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [2, 5])
+def test_secure_sum_stack_bit_identical_to_legacy_loop(n):
+    """The fused round's entry point: the cohort's payloads as one (n, ...)
+    stack per leaf, masked, summed and decoded where they lie."""
+    rng = np.random.default_rng(n)
+    vals = _trees(rng, n)
+    cfg = SecAggConfig(n, frac_bits=16, seed=n)
+    stack = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *vals)
+    new = secure_sum_stack(stack, cfg)
+    old = legacy_secure_sum(vals, cfg)
+    for a, b in zip(jax.tree_util.tree_leaves(new),
+                    jax.tree_util.tree_leaves(old)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="leading axis"):
+        secure_sum_stack(stack, SecAggConfig(n + 1, seed=0))
 
 
 def test_empty_and_scalar_leaves_round_trip():
@@ -129,6 +160,84 @@ def test_dropout_recovery_pads_regenerate_from_secret():
     np.testing.assert_allclose(got, expected, atol=n * 2**-15)
 
 
+# -- device field encode/decode == the float64 NumPy reference, bit for bit --
+
+
+def _f32_bits(bits):
+    return np.asarray(bits, np.uint32).view(np.float32)
+
+
+def _encode_inputs(kind: str) -> np.ndarray:
+    """float32 inputs of one class, both signs."""
+    rng = np.random.default_rng(17)
+    if kind == "normal":
+        mag = np.exp2(rng.uniform(-30.0, 14.0, 20_000))
+        x = np.concatenate([rng.normal(0.0, 3.0, 20_000), mag])
+    elif kind == "ties":  # (k + 0.5) / 2^16: exactly half a grid step
+        k = rng.integers(-(1 << 22), 1 << 22, 20_000)
+        x = np.concatenate([(k + 0.5) / 65536.0,
+                            (np.arange(-64, 64) + 0.5) / 65536.0])
+    elif kind == "zeros":  # +-0 and what rounds to it (ties to even)
+        x = np.asarray([0.0, -0.0, 2.0**-17, -2.0**-17, 2.0**-18,
+                        3 * 2.0**-18, 2.0**-40, -2.0**-100])
+    elif kind == "subnormal":
+        bits = np.concatenate([np.arange(1, 4096),
+                               rng.integers(1, 1 << 23, 10_000)])
+        x = _f32_bits(bits)
+    elif kind == "wrap":  # |x * 2^16| at and past 2^31 (and 2^32)
+        edges = np.float32([2.0**15, 2.0**16, 2.0**15 + 2.0**14])
+        x = np.concatenate([
+            edges, np.nextafter(edges, np.float32(0)),
+            np.nextafter(edges, np.float32(np.inf)),
+            rng.uniform(2.0**14, 2.0**17, 20_000),
+        ])
+    else:  # "huge": magnitudes up to 2^46
+        x = np.exp2(rng.uniform(15.0, 46.0, 20_000))
+        x = np.concatenate([x, np.float32([2.0**46, 2.0**45 + 2.0**30])])
+    x = np.asarray(x, np.float32)
+    return np.concatenate([x, -x])
+
+
+def _decode_inputs(kind: str) -> np.ndarray:
+    """uint32 field words of one bit-pattern class."""
+    rng = np.random.default_rng(23)
+    top = 1 << 32
+    if kind == "edges":
+        v = [0, 1, 2, 3, (1 << 24) - 1, 1 << 24, (1 << 24) + 1,
+             (1 << 31) - 1, 1 << 31, (1 << 31) + 1, top - (1 << 24) - 1,
+             top - 2, top - 1]
+    elif kind == "near_zero":  # small magnitudes of both signs
+        v = np.concatenate([np.arange(0, 1 << 16),
+                            np.arange(top - (1 << 16), top)])
+    elif kind == "rounded":  # |v| past 2^24: float32 rounds, ties to even
+        k = rng.integers(1 << 23, 1 << 24, 10_000)
+        tie = 4 * k + 2  # halfway between float32 neighbours in [2^25, 2^26)
+        big = rng.integers(1 << 24, 1 << 31, 10_000)
+        v = np.concatenate([tie, top - tie, big, top - big])
+    else:  # "uniform": every bit pattern equally likely
+        v = rng.integers(0, top, 40_000)
+    return np.asarray(v, np.uint64).astype(np.uint32)
+
+
+@pytest.mark.parametrize(
+    "kind", ["normal", "ties", "zeros", "subnormal", "wrap", "huge"])
+def test_device_encode_bit_identical_to_numpy(kind):
+    x = _encode_inputs(kind)
+    cfg = SecAggConfig(2, frac_bits=16)
+    got = np.asarray(_device_encode(jnp.asarray(x), cfg.frac_bits))
+    np.testing.assert_array_equal(got, _encode(x, cfg))
+
+
+@pytest.mark.parametrize("kind", ["edges", "near_zero", "rounded", "uniform"])
+@pytest.mark.parametrize("frac_bits", [0, 16])
+def test_device_decode_bit_identical_to_numpy(kind, frac_bits):
+    v = _decode_inputs(kind)
+    cfg = SecAggConfig(2, frac_bits=frac_bits)
+    got = np.asarray(_device_decode(jnp.asarray(v), cfg.frac_bits))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  _decode(v, cfg).view(np.uint32))
+
+
 # -- field encode/decode round-trips -----------------------------------------
 
 
@@ -145,7 +254,7 @@ def test_encode_decode_round_trip(x, frac_bits):
     q = np.round(np.float64(np.float32(x)) * cfg.scale)
     if abs(q) >= 2**31:
         return  # out of field range: wraps by design
-    got = _decode(_encode(np.float32(x), cfg), cfg)
+    got = _round_trip(np.float32(x), cfg)
     want = np.float32(q / cfg.scale)
     assert got == want
 
@@ -159,7 +268,7 @@ def test_integers_on_the_grid_survive_exactly(v, frac_bits):
     cfg = SecAggConfig(2, frac_bits=frac_bits)
     if v * cfg.scale >= 2**31:  # representable range shrinks with frac bits
         return
-    assert float(_decode(_encode(float(v), cfg), cfg)) == float(v)
+    assert float(_round_trip(float(v), cfg)) == float(v)
 
 
 def test_float_path_loses_big_integers_but_int_path_does_not():
@@ -167,7 +276,7 @@ def test_float_path_loses_big_integers_but_int_path_does_not():
     sum stays exact — the sum_sizes bugfix, demonstrated."""
     v = 366_390_673  # < 2^31, not representable in float32
     cfg = SecAggConfig(2, frac_bits=0)
-    assert float(_decode(_encode(float(v), cfg), cfg)) != float(v)
+    assert float(_round_trip(float(v), cfg)) != float(v)
     assert secure_sum_ints([v, 17], n_participants=2, seed=0) == v + 17
 
 
