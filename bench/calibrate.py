@@ -34,7 +34,7 @@ def readings(cell, seeds, n_control: int, n_faults: int, emit) -> None:
     clock = harness.CompileClock()
     for n, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        raw = cohort.cohort(cell.config, seed)
+        raw = cohort.cohort(cell.config, cell.traffic, seed)
         runs = [("program", None)]
         if n < n_faults:
             runs += list(faults.FAULTS.items())

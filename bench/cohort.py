@@ -1,12 +1,13 @@
-"""The benchmark's own cohort generator, keyed by a configuration's ``task``.
+"""The benchmark's own cohort generators.
 
 A frozen copy of the pancreas and GEMINI generators of
 ``repro.data.synthetic`` (same draws for the same seed), kept here so that a
-later change to the program's generator cannot move the yardstick.  The
-program receives only the ``(x, y)`` arrays of each hospital.
+later change to the program's generator cannot move the yardstick; the MLP
+kind (``bench/kinds/mlp.py``) picks one by the configuration's ``task``.
+The program receives only the ``(x, y)`` arrays of each hospital.
 
-``cohort(config, seed)`` returns one ``(x, y)`` pair of numpy arrays per
-hospital, with the configuration's published widths and cohort size.
+``cohort(config, traffic, seed)`` returns one ``(x, y)`` pair of numpy
+arrays per hospital, from the configuration's kind.
 """
 
 from __future__ import annotations
@@ -82,13 +83,10 @@ def pancreas(seed: int, n_total: int, n_silos: int, n_genes: int,
     return silos
 
 
-def cohort(config: dict, seed: int) -> list[tuple[np.ndarray, np.ndarray]]:
-    """The hospitals' raw data for ``config`` (a ``bench/configs`` file)."""
-    sizes = config["model"]["sizes"]
-    task = config["task"]
-    if task == "gemini":
-        return gemini(seed, config["examples"], config["hospitals"], sizes[0])
-    if task == "pancreas":
-        return pancreas(seed, config["examples"], config["hospitals"],
-                        sizes[0], sizes[-1])
-    raise ValueError(f"no generator for task {task!r}")
+def cohort(config: dict, traffic: dict,
+           seed: int) -> list[tuple[np.ndarray, np.ndarray]]:
+    """The hospitals' raw data for ``config`` (a ``bench/configs`` file)
+    under ``traffic``, drawn by its kind."""
+    from bench import harness
+
+    return harness.load_kind(config).cohort(config, traffic, seed)

@@ -32,9 +32,8 @@ import numpy as np
 NAMES = ("loss", "grad_norm", "update_norm", "signal", "epsilon")
 
 
-def _norms(tree: dict) -> dict[str, float]:
-    return {k: float(np.linalg.norm(v.astype(np.float64).ravel()))
-            for k, v in tree.items()}
+def _norm(v: np.ndarray) -> float:
+    return float(np.linalg.norm(v.astype(np.float64).ravel()))
 
 
 def _worst(gaps: dict[str, float], ref: dict[str, float],
@@ -44,38 +43,38 @@ def _worst(gaps: dict[str, float], ref: dict[str, float],
     return max(gaps[k] / max(ref[k], med) for k in keep)
 
 
-def _grad(p0: dict, p1: dict, lr: float) -> dict:
-    return {k: (p0[k].astype(np.float64) - p1[k].astype(np.float64)) / lr
-            for k in p0}
-
-
-def _norm_gap(a: dict, b: dict) -> float:
-    na, nb = _norms(a), _norms(b)
+def _norm_gap(na: dict[str, float], nb: dict[str, float]) -> float:
     return _worst({k: abs(na[k] - nb[k]) for k in nb}, nb)
 
 
 def readings(prog: dict, ref, eps_ref: float, lr: float) -> dict[str, float]:
     """``prog``: ``losses`` (rounds 1-3), ``p1``, ``p3`` (flat dicts) and
-    ``epsilon``; ``ref``: a ``reference.Trajectory`` of three rounds."""
+    ``epsilon``; ``ref``: a ``reference.Trajectory`` of three rounds.
+    Norms are taken leaf by leaf, so that one leaf's float64 copies are all
+    a large model holds on the host at a time."""
     p0 = ref.params[0]
     loss = max(abs(a - b) / abs(b)
                for a, b in zip(prog["losses"], ref.losses))
-    g_prog, g_ref = _grad(p0, prog["p1"], lr), _grad(p0, ref.params[1], lr)
-    d_prog = {k: prog["p3"][k].astype(np.float64) - p0[k] for k in p0}
-    d_ref = {k: ref.params[3][k].astype(np.float64) - p0[k] for k in p0}
     b, noise = ref.batches[0], ref.noise0
-    s_prog = {k: g_prog[k] * b - noise[k] for k in p0}
-    s_ref = {k: g_ref[k] * b - noise[k] for k in p0}
-    n_ref = _norms(s_ref)
-    med = statistics.median(n_ref.values())
-    keep = [k for k in n_ref if n_ref[k] >= 1e-3 * med]
-    signal = _worst({k: float(np.linalg.norm((s_prog[k] - s_ref[k]).ravel()))
-                     for k in keep}, n_ref, keep)
+    n = {side: {} for side in ("g_prog", "g_ref", "d_prog", "d_ref",
+                               "s_ref", "s_gap")}
+    for k in p0:
+        base = p0[k].astype(np.float64)
+        g_prog = (base - prog["p1"][k].astype(np.float64)) / lr
+        g_ref = (base - ref.params[1][k].astype(np.float64)) / lr
+        s_prog, s_ref = g_prog * b - noise[k], g_ref * b - noise[k]
+        n["g_prog"][k], n["g_ref"][k] = _norm(g_prog), _norm(g_ref)
+        n["s_ref"][k] = _norm(s_ref)
+        n["s_gap"][k] = float(np.linalg.norm((s_prog - s_ref).ravel()))
+        n["d_prog"][k] = _norm(prog["p3"][k].astype(np.float64) - p0[k])
+        n["d_ref"][k] = _norm(ref.params[3][k].astype(np.float64) - p0[k])
+    med = statistics.median(n["s_ref"].values())
+    keep = [k for k in n["s_ref"] if n["s_ref"][k] >= 1e-3 * med]
     return {
         "loss": loss,
-        "grad_norm": _norm_gap(g_prog, g_ref),
-        "update_norm": _norm_gap(d_prog, d_ref),
-        "signal": signal,
+        "grad_norm": _norm_gap(n["g_prog"], n["g_ref"]),
+        "update_norm": _norm_gap(n["d_prog"], n["d_ref"]),
+        "signal": _worst(n["s_gap"], n["s_ref"], keep),
         "epsilon": abs(prog["epsilon"] - eps_ref) / eps_ref,
     }
 

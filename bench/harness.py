@@ -6,9 +6,14 @@ configuration file (``bench/configs``), a traffic file
 (``bench/limits/<cell>.json``); per-layer metrics are readers in
 ``bench/layer_metrics/<metric>.py``.  Nothing here knows a cell by name.
 
+A configuration's ``model.kind`` names its kind, ``bench/kinds/<kind>.py``:
+the module that makes the cohort, the program's model and hospitals, the
+reference's model and the operation count for that kind of model.  Keys of
+the traffic file other than ``batch_size`` and ``secagg`` are the kind's.
+
 Set-up: generate the cohort from the seed, hand the hospitals' arrays to the
-program (its own ``normalize_participants``, its MLP, ``repro.arms.run``
-with the DeCaPH arm on the ``ideal`` backend), and let the run's first
+program (the kind's model and preparation, ``repro.arms.run`` with the
+DeCaPH arm on the ``ideal`` backend), and let the run's first
 ``WARMUP_ROUNDS`` rounds compile every program of the round.  The seed
 draws the cohort and the initial weights; the program's own stream (its
 Poisson draws, noise keys and SecAgg pads, ``ArmConfig.seed``) starts from
@@ -29,6 +34,7 @@ Then the reference follows the run's first ``FOLLOWED_ROUNDS`` rounds and
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -42,6 +48,7 @@ import numpy as np
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
+KINDS = BENCH / "kinds"
 WARMUP_ROUNDS = 1
 STREAM_SEED = 0
 FOLLOWED_ROUNDS = 3
@@ -80,14 +87,29 @@ def load_cell(name: str) -> Cell:
     )
 
 
-def load_reader(metric: str):
-    """The ``read(run)`` function of ``bench/layer_metrics/<metric>.py``."""
-    path = BENCH / "layer_metrics" / f"{metric}.py"
+@functools.cache
+def _load(path: Path, prefix: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(metric: str):
+    """The ``read(run)`` function of ``bench/layer_metrics/<metric>.py``."""
+    return _load(BENCH / "layer_metrics" / f"{metric}.py",
+                 "bench_metric_").read
+
+
+def load_kind(config: dict):
+    """The module ``<KINDS>/<kind>.py`` of ``config["model"]["kind"]``."""
+    path = KINDS / f"{config['model']['kind']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no module for model kind {config['model']['kind']!r}: "
+            f"looked for {path}")
+    return _load(path, "bench_kind_")
 
 
 class CompileClock:
@@ -231,13 +253,9 @@ def build_program(cell: Cell, raw, seed: int):
 
     import repro.arms as arms
     from repro.core.dp import DPConfig
-    from repro.models import tabular
 
     conf, dp = cell.config, cell.config["dp"]
-    silos = arms.normalize_participants(
-        [arms.Participant(x, y) for x, y in raw])
-    model = tabular.make_mlp_classifier(conf["model"]["sizes"],
-                                        task=conf["model"]["task"])
+    model, silos = load_kind(conf).program(conf, cell.traffic, raw)
     init = model.init_fn
     model = dataclasses.replace(
         model, init_fn=lambda _key: init(jax.random.key(seed)))
@@ -280,12 +298,21 @@ def program_outputs(report, window) -> dict:
     }
 
 
+def _reference(cell: Cell, raw, seed: int, dtype):
+    from bench import reference
+
+    model = load_kind(cell.config).reference(cell.config, cell.traffic)
+    return reference.run(model, raw, cell.config, cell.traffic, seed,
+                         STREAM_SEED, FOLLOWED_ROUNDS, dtype)
+
+
 def check(cell: Cell, raw, seed: int, prog: dict):
     """Readings of ``prog`` against the reference's first rounds."""
-    from bench import accountant, compare, reference
+    import jax.numpy as jnp
 
-    ref = reference.run(raw, cell.config, cell.traffic, seed, STREAM_SEED,
-                        FOLLOWED_ROUNDS)
+    from bench import accountant, compare
+
+    ref = _reference(cell, raw, seed, jnp.float32)
     dp = cell.config["dp"]
     eps_ref = accountant.epsilon(ref.rate, dp["noise_multiplier"],
                                  prog["stepped"], dp["delta"],
@@ -297,10 +324,9 @@ def control_outputs(cell: Cell, raw, seed: int) -> dict:
     """The reference in bfloat16, in the program's place (the control)."""
     import jax.numpy as jnp
 
-    from bench import accountant, reference
+    from bench import accountant
 
-    ctl = reference.run(raw, cell.config, cell.traffic, seed, STREAM_SEED,
-                        FOLLOWED_ROUNDS, jnp.bfloat16)
+    ctl = _reference(cell, raw, seed, jnp.bfloat16)
     dp = cell.config["dp"]
     return {
         "losses": ctl.losses, "p1": ctl.params[1],
@@ -351,7 +377,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     clock = CompileClock()
     device = device_info()
     t_cohort = time.perf_counter()
-    raw = cohort.cohort(cell.config, seed)
+    raw = cohort.cohort(cell.config, cell.traffic, seed)
     t_cohort = time.perf_counter() - t_cohort
     report, window = drive(cell, raw, seed, seconds, clock, trace)
     setup_s = window.t0 - t_start - t_cohort

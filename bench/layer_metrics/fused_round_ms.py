@@ -1,6 +1,6 @@
 """Fused round step: mean milliseconds per round of the program's
-``fused_round`` span (host draw, dispatch, host sync, and with SecAgg on the
-transfer of the payload stack to the host)."""
+``fused_round`` span (host draw, dispatch, and the wait for the cohort
+program and the fetch of its losses)."""
 
 
 def read(run):

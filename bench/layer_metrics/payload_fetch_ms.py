@@ -1,7 +1,7 @@
 """Fused round step: mean milliseconds per round of the program's
-``fused_round.fetch`` span (the wait for the cohort program and the one
-transfer of its losses, and with SecAgg on its payload stack, to the
-host)."""
+``fused_round.fetch`` span: the wait for the cohort program and the fetch
+of its losses; on the ``ideal`` backend the payload stack stays on the
+device, SecAgg on or off."""
 
 
 def read(run):

@@ -1,6 +1,7 @@
 """SecAgg and aggregation: mean milliseconds per round of the program's
-``secagg.encode`` span (the cohort's payloads to fixed-point field
-elements, flattened, on the host)."""
+``secagg.encode`` span: the dispatch of the program that encodes the
+cohort's payloads as fixed-point field elements and adds their masks on
+the device (``_cohort_ciphertexts`` on the ``ideal`` backend)."""
 
 
 def read(run):

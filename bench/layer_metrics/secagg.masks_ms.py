@@ -1,6 +1,6 @@
 """SecAgg and aggregation: mean milliseconds per round of the program's
-``secagg.masks`` span (the pair-pad program on the device and the fetch of
-the net-mask matrix to the host)."""
+``secagg.masks`` span: the dispatch of the pair-pad program; the net
+masks stay on the device."""
 
 
 def read(run):

@@ -1,6 +1,7 @@
 """SecAgg and aggregation: mean milliseconds per round of the program's
-``secagg.sum`` span (the ciphertexts' check, stack and field sum, the
-decode, and the sum's transfer back to the device)."""
+``secagg.sum`` span: the uploads' check, any Shamir reconstruction and
+recovery pads, and the dispatch of the program that sums and decodes the
+ciphertexts on the device."""
 
 
 def read(run):

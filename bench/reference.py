@@ -1,12 +1,12 @@
 """Plain reference of the first rounds of a DeCaPH run on the ideal backend.
 
 Follows the protocol the configuration states, written from the paper's
-Steps 1-7 and independent of the program under test:
+Steps 1-7 and independent of the program under test.  The model is the
+configuration's kind's (``bench/kinds/<kind>.py``, its ``reference``): its
+weights from ``seed``, the rows it feeds (its preparation phase, such as
+the MLP's pooled standardisation) and its per-example loss.  Here is the
+DP round and nothing of the model:
 
-* preparation: every hospital's features are standardised by the pooled
-  mean and standard deviation (computed here in float64);
-* weights: He-normal MLP weights and zero biases from ``jax.random.key(seed)``
-  (one split per layer, the first half of each layer's split for ``w``);
 * round ``t``: Poisson sampling at the shared rate ``B / N`` from one host
   ``numpy.random.default_rng(stream_seed)`` stream, hospitals in ascending
   order; per-example gradients clipped to ``C`` by their global L2 norm and
@@ -18,14 +18,15 @@ Steps 1-7 and independent of the program under test:
   sum); the sum is divided by the aggregate batch and applied by SGD.
 
 ``dtype`` float32 runs the model's arithmetic at ``highest`` matmul
-precision; bfloat16 is the control, which has to fail the comparison.
-Rows are processed in chunks of ``CHUNK`` so a published-width model fits.
+precision; bfloat16 is the control, which has to fail the comparison
+(floating rows are fed in ``dtype``, integer rows such as token ids as
+they are).  Rows are processed in chunks of the kind's ``chunk`` rows, so
+that a published-width model's per-example gradients fit.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 
 import jax
@@ -33,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 
 NOISE_SALT = 17
-CHUNK = 16
 
 
 def names(tree) -> list[str]:
@@ -49,45 +49,11 @@ def flat(tree) -> dict[str, np.ndarray]:
                                      jax.tree_util.tree_leaves(host))))
 
 
-def init_params(sizes, seed: int) -> dict:
-    keys = jax.random.split(jax.random.key(seed), len(sizes) - 1)
-    params = {}
-    for i in range(len(sizes) - 1):
-        k_w, _ = jax.random.split(keys[i])
-        w = jax.random.normal(k_w, (sizes[i], sizes[i + 1]), jnp.float32)
-        params[f"l{i}"] = {"w": w * math.sqrt(2.0 / sizes[i]),
-                           "b": jnp.zeros((sizes[i + 1],), jnp.float32)}
-    return params
-
-
-def standardize(raw):
-    """Pooled mean and std of the features (float64 sums)."""
-    n = sum(len(x) for x, _ in raw)
-    s = sum(np.sum(x, axis=0, dtype=np.float64) for x, _ in raw)
-    sq = sum(np.sum(np.square(x, dtype=np.float64), axis=0) for x, _ in raw)
-    mean = s / n
-    return mean, np.sqrt(np.maximum(sq / n - mean * mean, 0.0)) + 1e-8
-
-
-def _example_loss(params, x, y, task: str, n_layers: int):
-    h = x
-    for i in range(n_layers):
-        h = h @ params[f"l{i}"]["w"] + params[f"l{i}"]["b"]
-        if i < n_layers - 1:
-            h = jnp.maximum(h, 0)
-    if task == "binary":
-        z, y = h[0].astype(jnp.float32), y.astype(jnp.float32)
-        return jnp.maximum(z, 0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
-    return -jax.nn.log_softmax(h.astype(jnp.float32))[y]
-
-
-@partial(jax.jit, static_argnames=("task", "n_layers", "clip"))
-def _clipped_chunk(params, x, y, mask, *, task, n_layers, clip):
+def _clipped_chunk(example_loss, clip, params, x, y, mask):
     """(sum of clipped per-example gradients, sum of losses) over a chunk."""
 
     def one(x_i, y_i):
-        loss, g = jax.value_and_grad(_example_loss)(params, x_i, y_i, task,
-                                                    n_layers)
+        loss, g = jax.value_and_grad(example_loss)(params, x_i, y_i)
         leaves = jax.tree_util.tree_leaves(g)
         norm = jnp.sqrt(sum(jnp.sum(jnp.square(a.astype(jnp.float32)))
                             for a in leaves))
@@ -154,44 +120,50 @@ def draws(sizes: list[int], rate: float, seed: int, rounds: int):
             for _ in range(rounds)]
 
 
-def run(raw, config: dict, traffic: dict, seed: int, stream_seed: int,
-        rounds: int, dtype=jnp.float32) -> Trajectory:
-    """Follow ``rounds`` rounds from the weights of ``seed`` and the draws
-    and noise of ``stream_seed``; see the module docstring."""
-    sizes = config["model"]["sizes"]
-    task = config["model"]["task"]
+def _feed(x: np.ndarray, dtype):
+    return (jnp.asarray(x, dtype) if np.issubdtype(x.dtype, np.floating)
+            else jnp.asarray(x))
+
+
+def run(model, raw, config: dict, traffic: dict, seed: int,
+        stream_seed: int, rounds: int, dtype=jnp.float32) -> Trajectory:
+    """Follow ``rounds`` rounds of ``model`` (a kind's ``reference``) from
+    the weights of ``seed`` and the draws and noise of ``stream_seed``; see
+    the module docstring.  ``model.rows(raw)`` gives each hospital's
+    ``(x, y)``: arrays, or objects with ``shape``, ``dtype`` and indexing
+    by an array of row numbers."""
     dp = config["dp"]
     frac_bits = config["secagg_frac_bits"]
-    n_layers = len(sizes) - 1
     h = len(raw)
     rate = traffic["batch_size"] / sum(len(x) for x, _ in raw)
-    mean, std = standardize(raw)
+    fed = model.rows(raw)
     noise_std = (dp["clip_norm"] * dp["noise_multiplier"]
                  / jnp.sqrt(float(h)))
-    chunk = partial(_clipped_chunk, task=task, n_layers=n_layers,
-                    clip=float(dp["clip_norm"]))
+    chunk = jax.jit(partial(_clipped_chunk, model.example_loss,
+                            float(dp["clip_norm"])))
     precision = "highest" if dtype == jnp.float32 else "default"
     params = jax.tree_util.tree_map(lambda a: a.astype(dtype),
-                                    init_params(sizes, seed))
+                                    model.init(seed))
     traj = Trajectory([flat(params)], [], [], {}, rate)
     for t, picks in enumerate(draws([len(x) for x, _ in raw], rate,
                                     stream_seed, rounds)):
         total = noise_total = None
         losses = []
         for i, idx in enumerate(picks):
+            xs, ys = fed[i]
             g_sum = jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, jnp.float32), params)
             loss_sum = jnp.zeros((), jnp.float32)
-            for lo in range(0, len(idx), CHUNK):
-                rows = idx[lo:lo + CHUNK]
-                x = np.zeros((CHUNK, sizes[0]), np.float32)
-                x[:len(rows)] = (raw[i][0][rows] - mean) / std
-                y = np.zeros((CHUNK,), raw[i][1].dtype)
-                y[:len(rows)] = raw[i][1][rows]
-                mask = np.zeros((CHUNK,), np.float32)
+            for lo in range(0, len(idx), model.chunk):
+                rows = idx[lo:lo + model.chunk]
+                x = np.zeros((model.chunk,) + xs.shape[1:], xs.dtype)
+                x[:len(rows)] = xs[rows]
+                y = np.zeros((model.chunk,) + ys.shape[1:], ys.dtype)
+                y[:len(rows)] = ys[rows]
+                mask = np.zeros((model.chunk,), np.float32)
                 mask[:len(rows)] = 1.0
                 with jax.default_matmul_precision(precision):
-                    g, l = chunk(params, jnp.asarray(x, dtype), y, mask)
+                    g, l = chunk(params, _feed(x, dtype), y, mask)
                 g_sum = _tree_add(g_sum, g)
                 loss_sum = loss_sum + l
             losses.append(float(loss_sum) / max(len(idx), 1))

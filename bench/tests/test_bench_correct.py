@@ -3,7 +3,6 @@ each cell's limits; the control (the reference in bfloat16, in the
 program's place) fails them; and a run with a fault planted under the timed
 path (``bench/faults.py``) comes out not correct."""
 
-import copy
 import json
 import time
 from pathlib import Path
@@ -17,17 +16,13 @@ from bench import cohort, compare, faults, harness
 ROOT = Path(__file__).resolve().parents[2]
 CELLS = [w["name"] for w in
          json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
-TINY = {"pancreas": ([64, 32, 16, 4], 600), "gemini": ([40, 16, 8, 1], 800)}
 SEED = 2**31 + 11
 
 
 def tiny(name: str) -> harness.Cell:
-    """The cell with its widths and cohort cut to a test's size."""
+    """The cell with its configuration cut to a test's size by its kind."""
     cell = harness.load_cell(name)
-    cell.config = copy.deepcopy(cell.config)
-    sizes, examples = TINY[cell.config["task"]]
-    cell.config["model"]["sizes"] = sizes
-    cell.config["examples"] = examples
+    cell.config = harness.load_kind(cell.config).tiny(cell.config)
     return cell
 
 
@@ -48,7 +43,7 @@ def test_sound_run_is_correct(name):
 @pytest.mark.parametrize("name", CELLS)
 def test_control_is_not_correct(name):
     cell = tiny(name)
-    raw = cohort.cohort(cell.config, SEED)
+    raw = cohort.cohort(cell.config, cell.traffic, SEED)
     values = harness.check(cell, raw, SEED,
                               harness.control_outputs(cell, raw, SEED))
     assert not compare.judge(values, cell.limits)
@@ -66,7 +61,8 @@ def test_seed_draws_the_cohort_and_weights_not_the_stream():
     """Two seeds give two cohorts and two sets of initial weights, and one
     program stream, so no seed compiles a round step of its own."""
     cell = tiny(CELLS[0])
-    built = [harness.build_program(cell, cohort.cohort(cell.config, s), s)
+    built = [harness.build_program(cell, cohort.cohort(cell.config,
+                                                     cell.traffic, s), s)
              for s in (SEED, SEED + 1)]
     (m1, h1, c1), (m2, h2, c2) = built
     assert c1.seed == c2.seed == harness.STREAM_SEED

@@ -1,11 +1,11 @@
-"""The FLOP count and the table of peaks."""
+"""The MLP kind's FLOP count and the table of peaks."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from bench import flops
+from bench import flops, harness
 
 ROOT = Path(__file__).resolve().parents[2]
 
@@ -14,10 +14,9 @@ ROOT = Path(__file__).resolve().parents[2]
 def test_mlp_flops_are_six_times_the_weights(name):
     conf = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
                       .read_text())
-    sizes = conf["model"]["sizes"]
-    biases = sum(sizes[1:])
-    assert (flops.mlp_train_flops_per_example(sizes)
-            == 6 * (conf["params"] - biases))
+    biases = sum(conf["model"]["sizes"][1:])
+    work = harness.load_kind(conf).train_flops_per_example(conf, {})
+    assert work == 6 * (conf["params"] - biases)
 
 
 def test_v5e_peaks_have_a_source():

@@ -9,7 +9,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import compare, harness, reference
+from bench import compare, harness
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -32,27 +32,34 @@ def test_every_workload_loads(w):
     assert cell.per_layer
 
 
+def _count(tree) -> int:
+    return sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(tree))
+
+
 @pytest.mark.parametrize("c", BENCH["configs"], ids=lambda c: c["name"])
 def test_every_config_loads_with_its_parameter_count(c):
+    """The program's initial weights and the reference's both hold the
+    configuration's ``params``.  The program's model comes from the
+    configuration alone; its hospitals here are a tiny cohort's."""
     conf = json.loads((ROOT / c["file"]).read_text())
     assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
-    sizes = conf["model"]["sizes"]
-    shapes = jax.eval_shape(lambda: reference.init_params(sizes, 0))
-    n = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(shapes))
-    assert n == conf["params"]
+    kind = harness.load_kind(conf)
+    w = next(w for w in BENCH["workloads"] if w["config"] == c["name"])
+    traffic = harness.load_cell(w["name"]).traffic
+    assert _count(jax.eval_shape(
+        lambda: kind.reference(conf, traffic).init(0))) == conf["params"]
 
-    from repro.models import tabular
-
-    model = tabular.make_mlp_classifier(sizes, task=conf["model"]["task"])
-    shapes = jax.eval_shape(model.init_fn, jax.random.key(0))
-    n = sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(shapes))
-    assert n == conf["params"]
+    raw = kind.cohort(kind.tiny(conf), traffic, 0)
+    model, _ = kind.program(conf, traffic, raw)
+    assert _count(jax.eval_shape(model.init_fn,
+                                 jax.random.key(0))) == conf["params"]
 
 
 def test_published_parameter_counts():
     counts = {c["name"]: json.loads((ROOT / c["file"]).read_text())["params"]
               for c in BENCH["configs"]}
-    assert counts == {"pancreas-mlp": 15_659_504, "gemini-mlp": 166_771}
+    assert counts["pancreas-mlp"] == 15_659_504
+    assert counts["gemini-mlp"] == 166_771
 
 
 def test_names_and_units_use_allowed_characters():
@@ -80,4 +87,7 @@ def test_traffic_files_are_found_by_name():
     for w in BENCH["workloads"]:
         traffic = json.loads(
             (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
-        assert set(traffic) == {"secagg", "batch_size"}
+        # the rest of a traffic file's keys are its configuration's kind's
+        assert {"secagg", "batch_size"} <= set(traffic)
+        assert isinstance(traffic["secagg"], bool)
+        assert traffic["batch_size"] > 0
