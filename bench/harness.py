@@ -162,13 +162,18 @@ class Window:
     """The ``on_round`` seam: opens the window after the warm-up rounds,
     closes it at the end of the first round that ends after ``seconds`` (and
     no earlier than the last followed round), and keeps the parameters after
-    rounds 1 and ``FOLLOWED_ROUNDS``."""
+    rounds 1 and ``FOLLOWED_ROUNDS`` on the host (``kept``, flat dicts), so
+    that the program has the device to itself.  Round 1's copy is taken
+    before the window opens; round ``FOLLOWED_ROUNDS``'s is started
+    asynchronously in the window and collected at the next round or at the
+    close."""
 
     def __init__(self, cfg, seconds: float, clock: CompileClock,
                  trace: bool) -> None:
         self.cfg, self.seconds, self.clock, self.trace = (cfg, seconds,
                                                           clock, trace)
-        self.kept: dict[int, object] = {}
+        self.kept: dict[int, dict] = {}
+        self.copying = None                # (t, device tree) on its way
         self.first = None                  # first round of the window
         self.t0 = self.t1 = None
         self.compile_s0 = 0.0
@@ -181,8 +186,17 @@ class Window:
         self.stop_trace_s = 0.0            # spent inside the window
 
     def on_round(self, t: int, params) -> None:
-        if t in (0, FOLLOWED_ROUNDS - 1):
-            self.kept[t] = params
+        import jax
+
+        from bench import reference
+
+        self._collect()
+        if t == 0:
+            self.kept[t] = reference.flat(params)
+        elif t == FOLLOWED_ROUNDS - 1:
+            for leaf in jax.tree_util.tree_leaves(params):
+                leaf.copy_to_host_async()
+            self.copying = (t, params)
         if self.t1 is not None:
             return
         if self.t0 is None:
@@ -194,6 +208,15 @@ class Window:
         if (t >= FOLLOWED_ROUNDS - 1
                 and time.perf_counter() - self.t0 >= self.seconds):
             self._close(params)
+
+    def _collect(self) -> None:
+        """Finish the host copy under way and drop its device tree."""
+        from bench import reference
+
+        if self.copying is not None:
+            t, params = self.copying
+            self.kept[t] = reference.flat(params)
+            self.copying = None
 
     def _open(self, t: int, params) -> None:
         import jax
@@ -230,6 +253,7 @@ class Window:
         self.dispatches = jit_dispatches() - self.dispatches
         if self.tracing:
             self._stop_trace(params)
+        self._collect()
         if self.trace:
             obs.disable()
         # the program's own stop: should_stop() reads the budget next
@@ -287,12 +311,10 @@ def drive(cell: Cell, raw, seed: int, seconds: float, clock: CompileClock,
 
 def program_outputs(report, window) -> dict:
     """What the comparison reads from the program's run."""
-    from bench import reference
-
     return {
         "losses": [log.loss for log in report.logs[:FOLLOWED_ROUNDS]],
-        "p1": reference.flat(window.kept[0]),
-        "p3": reference.flat(window.kept[FOLLOWED_ROUNDS - 1]),
+        "p1": window.kept[0],
+        "p3": window.kept[FOLLOWED_ROUNDS - 1],
         "epsilon": float(report.epsilon),
         "stepped": sum(log.aggregate_batch > 0 for log in report.logs),
     }
@@ -368,6 +390,13 @@ def _memory_peak() -> int | None:
     return max(peaks) if peaks else None
 
 
+def _live_bytes() -> int:
+    """Bytes of the arrays still alive on the devices."""
+    import jax
+
+    return sum(a.nbytes for a in jax.live_arrays())
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float) -> dict:
     """One run of ``cell``: the result object of the benchmark's last line.
@@ -426,6 +455,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     prog = program_outputs(report, window)
     del report, window
     gc.collect()
+    print(f"device bytes live before the reference: {_live_bytes()}",
+          flush=True)
     t_ref = time.perf_counter()
     values = check(cell, raw, seed, prog)
     print(f"reference: {time.perf_counter() - t_ref!r} s", flush=True)

@@ -96,6 +96,12 @@ def _tree_add(a, b):
     return b if a is None else jax.tree_util.tree_map(jnp.add, a, b)
 
 
+def _host_add(a: dict, b: dict) -> dict:
+    """``a + b`` leaf by leaf on flat host dicts (``b`` where ``a`` is
+    empty): float32 adds round as ``jnp.add`` does."""
+    return {k: a[k] + b[k] for k in b} if a else b
+
+
 @dataclasses.dataclass
 class Trajectory:
     """What the reference (or the control) produced over its rounds.
@@ -125,13 +131,24 @@ def _feed(x: np.ndarray, dtype):
             else jnp.asarray(x))
 
 
+def _chunk_program(example_loss, clip: float):
+    """The compiled chunk: ``(params, x, y, mask) -> (g, loss_sum)``."""
+    return jax.jit(partial(_clipped_chunk, example_loss, clip))
+
+
 def run(model, raw, config: dict, traffic: dict, seed: int,
         stream_seed: int, rounds: int, dtype=jnp.float32) -> Trajectory:
     """Follow ``rounds`` rounds of ``model`` (a kind's ``reference``) from
     the weights of ``seed`` and the draws and noise of ``stream_seed``; see
     the module docstring.  ``model.rows(raw)`` gives each hospital's
     ``(x, y)``: arrays, or objects with ``shape``, ``dtype`` and indexing
-    by an array of row numbers."""
+    by an array of row numbers.
+
+    At most five model-sized trees are on the device at a time outside the
+    chunk program: ``params``, the cohort's ``total``, the hospital's
+    ``g_sum``, one chunk output or noise share, and the result being formed.
+    Each tree is dropped as soon as it has been added in; the round-1 noise
+    sum is kept on the host alone."""
     dp = config["dp"]
     frac_bits = config["secagg_frac_bits"]
     h = len(raw)
@@ -139,15 +156,14 @@ def run(model, raw, config: dict, traffic: dict, seed: int,
     fed = model.rows(raw)
     noise_std = (dp["clip_norm"] * dp["noise_multiplier"]
                  / jnp.sqrt(float(h)))
-    chunk = jax.jit(partial(_clipped_chunk, model.example_loss,
-                            float(dp["clip_norm"])))
+    chunk = _chunk_program(model.example_loss, float(dp["clip_norm"]))
     precision = "highest" if dtype == jnp.float32 else "default"
     params = jax.tree_util.tree_map(lambda a: a.astype(dtype),
                                     model.init(seed))
     traj = Trajectory([flat(params)], [], [], {}, rate)
     for t, picks in enumerate(draws([len(x) for x, _ in raw], rate,
                                     stream_seed, rounds)):
-        total = noise_total = None
+        total = None
         losses = []
         for i, idx in enumerate(picks):
             xs, ys = fed[i]
@@ -165,14 +181,18 @@ def run(model, raw, config: dict, traffic: dict, seed: int,
                 with jax.default_matmul_precision(precision):
                     g, l = chunk(params, _feed(x, dtype), y, mask)
                 g_sum = _tree_add(g_sum, g)
+                del g
                 loss_sum = loss_sum + l
             losses.append(float(loss_sum) / max(len(idx), 1))
             noise = _noise(stream_seed, t, i, g_sum, noise_std)
+            if t == 0:   # ascending hospital order, on the host
+                traj.noise0 = _host_add(traj.noise0, flat(noise))
             payload = _tree_add(g_sum, noise)
+            del g_sum, noise
             if traffic["secagg"]:
                 payload = _quantize(payload, frac_bits)
             total = _tree_add(total, payload)   # ascending hospital order
-            noise_total = _tree_add(noise_total, noise)
+            del payload
         if traffic["secagg"]:
             total = _dequantize(total, frac_bits)
         batch = sum(len(idx) for idx in picks)
@@ -181,9 +201,8 @@ def run(model, raw, config: dict, traffic: dict, seed: int,
                 lambda p, g: (p.astype(jnp.float32)
                               - config["lr"] * (g / batch)).astype(dtype),
                 params, total)
+        del total
         traj.params.append(flat(params))
         traj.losses.append(float(np.mean(losses)))
         traj.batches.append(batch)
-        if t == 0:
-            traj.noise0 = flat(noise_total)
     return traj

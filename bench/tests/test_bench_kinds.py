@@ -168,3 +168,104 @@ def test_mlp_reference_rounds_match_their_digests(name, dtype):
     traj = reference.run(kind.reference(config, cell.traffic), raw, config,
                          cell.traffic, SEED, 0, 3, getattr(jnp, dtype))
     assert digest(traj) == DIGESTS[name, dtype]
+
+
+def parent_run(model, raw, config, traffic, seed, stream_seed, rounds,
+               dtype=jnp.float32):
+    """``reference.run`` as it was before it dropped each tree once added
+    in (8-9 model-sized trees on the device), kept as the yardstick of its
+    arithmetic."""
+    from functools import partial
+
+    import jax
+
+    dp = config["dp"]
+    frac_bits = config["secagg_frac_bits"]
+    h = len(raw)
+    rate = traffic["batch_size"] / sum(len(x) for x, _ in raw)
+    fed = model.rows(raw)
+    noise_std = (dp["clip_norm"] * dp["noise_multiplier"]
+                 / jnp.sqrt(float(h)))
+    chunk = jax.jit(partial(reference._clipped_chunk, model.example_loss,
+                            float(dp["clip_norm"])))
+    precision = "highest" if dtype == jnp.float32 else "default"
+    params = jax.tree_util.tree_map(lambda a: a.astype(dtype),
+                                    model.init(seed))
+    traj = reference.Trajectory([reference.flat(params)], [], [], {}, rate)
+    for t, picks in enumerate(reference.draws([len(x) for x, _ in raw],
+                                              rate, stream_seed, rounds)):
+        total = noise_total = None
+        losses = []
+        for i, idx in enumerate(picks):
+            xs, ys = fed[i]
+            g_sum = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), params)
+            loss_sum = jnp.zeros((), jnp.float32)
+            for lo in range(0, len(idx), model.chunk):
+                rows = idx[lo:lo + model.chunk]
+                x = np.zeros((model.chunk,) + xs.shape[1:], xs.dtype)
+                x[:len(rows)] = xs[rows]
+                y = np.zeros((model.chunk,) + ys.shape[1:], ys.dtype)
+                y[:len(rows)] = ys[rows]
+                mask = np.zeros((model.chunk,), np.float32)
+                mask[:len(rows)] = 1.0
+                with jax.default_matmul_precision(precision):
+                    g, l = chunk(params, reference._feed(x, dtype), y, mask)
+                g_sum = reference._tree_add(g_sum, g)
+                loss_sum = loss_sum + l
+            losses.append(float(loss_sum) / max(len(idx), 1))
+            noise = reference._noise(stream_seed, t, i, g_sum, noise_std)
+            payload = reference._tree_add(g_sum, noise)
+            if traffic["secagg"]:
+                payload = reference._quantize(payload, frac_bits)
+            total = reference._tree_add(total, payload)
+            noise_total = reference._tree_add(noise_total, noise)
+        if traffic["secagg"]:
+            total = reference._dequantize(total, frac_bits)
+        batch = sum(len(idx) for idx in picks)
+        if batch:
+            params = jax.tree_util.tree_map(
+                lambda p, g: (p.astype(jnp.float32)
+                              - config["lr"] * (g / batch)).astype(dtype),
+                params, total)
+        traj.params.append(reference.flat(params))
+        traj.losses.append(float(np.mean(losses)))
+        traj.batches.append(batch)
+        if t == 0:
+            traj.noise0 = reference.flat(noise_total)
+    return traj
+
+
+def assert_same_trajectory(a, b):
+    assert len(a.params) == len(b.params) == 4
+    for pa, pb in zip([*a.params, a.noise0], [*b.params, b.noise0]):
+        assert pa.keys() == pb.keys()
+        for k in pa:
+            assert pa[k].dtype == pb[k].dtype and np.array_equal(pa[k],
+                                                                 pb[k]), k
+    assert (a.losses, a.batches, a.rate) == (b.losses, b.batches, b.rate)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
+def test_reference_matches_the_parent_loop(name, dtype):
+    """Every cell at its kind's tiny cut: the same trajectory, bit for bit,
+    as the loop that kept every tree."""
+    cell = harness.load_cell(name)
+    kind = harness.load_kind(cell.config)
+    config = kind.tiny(cell.config)
+    raw = cohort.cohort(config, cell.traffic, SEED)
+    args = (kind.reference(config, cell.traffic), raw, config, cell.traffic,
+            SEED, 0, 3, getattr(jnp, dtype))
+    assert_same_trajectory(reference.run(*args), parent_run(*args))
+
+
+@pytest.mark.parametrize("secagg", [True, False], ids=["secagg", "nosecagg"])
+def test_reference_matches_the_parent_loop_on_the_token_stub(stub_cell,
+                                                             secagg):
+    stub_cell.traffic["secagg"] = secagg
+    kind = harness.load_kind(stub_cell.config)
+    raw = cohort.cohort(stub_cell.config, stub_cell.traffic, SEED)
+    args = (kind.reference(stub_cell.config, stub_cell.traffic), raw,
+            stub_cell.config, stub_cell.traffic, SEED, 0, 3)
+    assert_same_trajectory(reference.run(*args), parent_run(*args))
