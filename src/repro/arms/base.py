@@ -131,6 +131,27 @@ class ArmConfig:
 # -- shared numerics helpers -------------------------------------------------
 
 
+def poisson_rows(
+    rng: np.random.Generator,
+    n: int,
+    rate: float,
+    pad_to: int,
+) -> tuple[np.ndarray, int]:
+    """``poisson_batch``'s draw alone: the chosen row indices of a silo of
+    ``n`` examples and the pad that holds them (``pad_to``, or grown)."""
+    idx = np.nonzero(rng.random(n) < rate)[0]
+    k = len(idx)
+    if k > pad_to:
+        grown = 1 << int(np.ceil(np.log2(k)))
+        logger.warning(
+            "poisson_batch: draw of %d examples exceeded the padded batch %d; "
+            "growing the pad to %d for this round (jit retrace). Raise "
+            "max_pad_batch to avoid this.", k, pad_to, grown,
+        )
+        pad_to = grown
+    return idx, pad_to
+
+
 def poisson_batch(
     rng: np.random.Generator,
     part: Participant,
@@ -146,17 +167,8 @@ def poisson_batch(
     the subsampled-RDP privacy analysis, so it must never happen quietly;
     the growth is logged because it retriggers jit tracing for that shape.
     """
-    sel = rng.random(len(part)) < rate
-    idx = np.nonzero(sel)[0]
+    idx, pad_to = poisson_rows(rng, len(part), rate, pad_to)
     k = len(idx)
-    if k > pad_to:
-        grown = 1 << int(np.ceil(np.log2(k)))
-        logger.warning(
-            "poisson_batch: draw of %d examples exceeded the padded batch %d; "
-            "growing the pad to %d for this round (jit retrace). Raise "
-            "max_pad_batch to avoid this.", k, pad_to, grown,
-        )
-        pad_to = grown
     xb = np.zeros((pad_to,) + part.x.shape[1:], part.x.dtype)
     yb = np.zeros((pad_to,) + part.y.shape[1:], part.y.dtype)
     xb[:k] = part.x[idx]

@@ -96,9 +96,21 @@ class DeCaPHArm(RoundArm):
             stack, losses = jax.vmap(one)(bx, by, masks, idxs)
             return stack, fused.seq_tree_sum(stack, bx.shape[0]), losses
 
+        def gathered_step(params, x_all, y_all, rows, masks, salt_t, idxs,
+                          n_shares):
+            """``cohort_step`` over the batch gathered here from the rows on
+            the device (``fused.device_rows``): arguments, never constants."""
+            return cohort_step(params, x_all[rows], y_all[rows], masks,
+                               salt_t, idxs, n_shares)
+
+        # the host-stacked program serves a mesh executor, which shards the
+        # stacked batch along its example axis; the gathered one all else
         self._fused_step, self._fused_step_slim = fused.instrumented_jit_pair(
             cohort_step, static_argnums=(6,)
         )
+        self._gather_step, self._gather_step_slim = (
+            fused.instrumented_jit_pair(gathered_step, static_argnums=(7,)))
+        self._rows = None  # (x, y) on the device, put there by the first round
 
     # --- schedule -------------------------------------------------------------
 
@@ -152,15 +164,24 @@ class DeCaPHArm(RoundArm):
     def fused_round(self, params, active, t, rng, n_shares, need_payloads,
                     need_reduced=True):
         cb = fused.stack_poisson(
-            rng, self.participants, active, self.rate, self.pad
+            rng, self.participants, active, self.rate, self.pad,
+            host_stack=False,
         )
-        args = (params, cb.x, cb.y, cb.masks,
+        if fused.active_executor() is not None:
+            full, slim = self._fused_step, self._fused_step_slim
+            batch = (cb.x, cb.y)
+        else:
+            if self._rows is None:
+                self._rows = fused.device_rows(self.participants)
+            full, slim = self._gather_step, self._gather_step_slim
+            batch = (*self._rows, cb.rows)
+        args = (params, *batch, cb.masks,
                 np.int32(_NOISE_SALT + t), np.asarray(active, np.int32),
                 n_shares)
         if need_reduced:
-            stack, reduced, losses = self._fused_step(*args)
+            stack, reduced, losses = full(*args)
         else:
-            (stack, losses), reduced = self._fused_step_slim(*args), None
+            (stack, losses), reduced = slim(*args), None
         contribs = fused.build_contributions(
             active, stack, losses, cb.sizes, need_payloads
         )

@@ -2,9 +2,10 @@
 
 The per-participant round loop costs H separate jit dispatches plus H host
 syncs (``float(loss)`` inside each ``contribution()``) per round.  The fused
-hot path stacks the cohort's padded Poisson batches on a leading participant
-axis and vmaps the arm's per-silo numerics across it inside ONE jit'd
-program — noise keys are pure ``fold_in`` functions of ``(round, index)``,
+hot path lays the cohort's padded Poisson batches on a leading participant
+axis (stacked on the host, or gathered inside the program from the rows
+``device_rows`` keeps on the device) and vmaps the arm's per-silo numerics
+across it inside ONE jit'd program — noise keys are pure ``fold_in`` functions of ``(round, index)``,
 so batching them changes nothing about what each participant draws.  Metrics
 come back as one stacked array: a single host sync per round.
 
@@ -41,7 +42,7 @@ import jax
 import numpy as np
 
 import repro.obs as obs
-from repro.arms.base import Contribution, Participant, poisson_batch
+from repro.arms.base import Contribution, Participant, poisson_rows
 
 # -- jit dispatch accounting -------------------------------------------------
 # Hoisted to ``repro.instrument`` so the serving tier (DESIGN.md §9) shares
@@ -59,33 +60,86 @@ from repro.instrument import (  # noqa: F401
 PyTree = Any
 
 
-# -- host-side cohort stacking ----------------------------------------------
+# -- the cohort's Poisson batch ---------------------------------------------
 
 
 @dataclasses.dataclass
 class CohortBatch:
-    """The active cohort's Poisson draws, stacked to one static shape.
+    """The active cohort's Poisson draws, at one static shape.
 
-    ``x``/``y`` have leading axis ``n_active`` (plus a steps axis when
-    ``steps`` was requested); ``masks`` flags the real examples inside each
-    pad; ``counts`` is the per-draw real-example count (int32, same leading
-    axes); ``sizes`` is the per-participant total — host ints, known before
-    the dispatch, which is what lets aggregate-batch math stay off-device.
+    ``rows`` holds, per slot, the example's global row index: the
+    participants' examples concatenated in participant order, as
+    ``device_rows`` lays them out, with pad slots at the zero row one past
+    the last example.  ``masks`` flags the real examples inside each pad;
+    ``counts`` is the per-draw real-example count (int32); all three share
+    the leading axes ``n_active`` (plus a steps axis when ``steps`` was
+    requested).  ``sizes`` is the per-participant total — host ints, known
+    before the dispatch, which is what lets aggregate-batch math stay
+    off-device.
+
+    ``x``/``y`` are the examples the rows pick, stacked on the host (zero
+    rows in the pad slots): built by ``stack_poisson`` when asked to stack,
+    else on first read and kept in ``stacked``.
     """
 
-    x: np.ndarray
-    y: np.ndarray
     masks: np.ndarray
     counts: np.ndarray
     sizes: list[int]
+    rows: np.ndarray
+    source: Sequence[Participant] = ()
+    stacked: dict[str, np.ndarray] = dataclasses.field(
+        default_factory=dict, repr=False)
+
+    @property
+    def x(self) -> np.ndarray:
+        return self._stack("x")
+
+    @property
+    def y(self) -> np.ndarray:
+        return self._stack("y")
+
+    def _stack(self, leaf: str) -> np.ndarray:
+        if leaf not in self.stacked:
+            self.stacked[leaf] = _host_rows(self.source, self.rows, leaf)
+        return self.stacked[leaf]
 
 
-def _repad(arr: np.ndarray, pad_to: int) -> np.ndarray:
-    if arr.shape[0] == pad_to:
-        return arr
-    out = np.zeros((pad_to,) + arr.shape[1:], arr.dtype)
-    out[: arr.shape[0]] = arr
+def _row_starts(participants: Sequence[Participant]) -> np.ndarray:
+    """Each participant's first global row, then the zero row's index."""
+    return np.cumsum([0] + [len(p) for p in participants])
+
+
+def _host_rows(participants: Sequence[Participant], rows: np.ndarray,
+               leaf: str) -> np.ndarray:
+    """``leaf`` of the examples ``rows`` picks, gathered on the host."""
+    arrs = [getattr(p, leaf) for p in participants]
+    starts = _row_starts(participants)
+    dtype = np.result_type(*{a.dtype for a in arrs})
+    out = np.zeros(rows.shape + arrs[0].shape[1:], dtype)
+    flat_out = out.reshape((-1,) + arrs[0].shape[1:])
+    flat = rows.reshape(-1)
+    real = flat < starts[-1]
+    silo = np.searchsorted(starts, flat, side="right") - 1
+    for s in np.unique(silo[real]):
+        sel = np.flatnonzero(real & (silo == s))
+        flat_out[sel] = arrs[s][flat[sel] - starts[s]]
     return out
+
+
+def device_rows(participants: Sequence[Participant]) -> tuple[jax.Array,
+                                                              jax.Array]:
+    """Every participant's ``x`` and ``y`` on the device, concatenated in
+    participant order with one zero row appended: the arrays
+    ``CohortBatch.rows`` indexes, so a gather there gives the host stack."""
+    out = []
+    for leaf in ("x", "y"):
+        arrs = [getattr(p, leaf) for p in participants]
+        n = sum(len(a) for a in arrs)
+        dtype = np.result_type(*{a.dtype for a in arrs})
+        host = np.zeros((n + 1,) + arrs[0].shape[1:], dtype)
+        np.concatenate(arrs, out=host[:n])
+        out.append(jax.device_put(host))
+    return out[0], out[1]
 
 
 def stack_poisson(
@@ -95,8 +149,10 @@ def stack_poisson(
     rate: float | Sequence[float],
     pad: int | Sequence[int],
     steps: int | None = None,
+    *,
+    host_stack: bool = True,
 ) -> CohortBatch:
-    """Stack each active participant's Poisson draw(s) to one static shape.
+    """Draw each active participant's Poisson batch(es) at one static shape.
 
     Consumes ``rng`` in exactly the order the per-participant loop would:
     ascending participant index, and (when ``steps`` is given) each
@@ -108,13 +164,16 @@ def stack_poisson(
     ``rate``/``pad`` may be sequences indexed by *absolute* participant
     index (ragged local-DP arms like primia: every client has its own
     sampling rate and pad); each draw then uses its own rate/pad exactly
-    like the per-participant loop, and the stack re-pads every row to the
+    like the per-participant loop, and the batch re-pads every row to the
     cohort max.  Extra zero rows contribute exactly nothing to masked
     sums, so padding never changes any number.
 
-    Under an active mesh execution context the cohort pad is rounded up to
-    the mesh's data-axis size (again mask-inert) and the stacked batch
-    arrays are marked for sharding along the example axis.
+    With ``host_stack`` the examples are stacked on the host here
+    (``CohortBatch.x``/``y``); without it only the row indices are made,
+    for a program that gathers from ``device_rows``.  Under an active mesh
+    execution context the batch is always stacked: the cohort pad is
+    rounded up to the mesh's data-axis size (again mask-inert) and the
+    stacked arrays are marked for sharding along the example axis.
     """
     t0 = obs.now()  # host-RNG phase: the one per-round host-side cost
     rate_of = (rate.__getitem__ if not isinstance(rate, (int, float))
@@ -123,40 +182,44 @@ def stack_poisson(
               else lambda i: pad)
     executor = active_executor()
     k_steps = 1 if steps is None else steps
-    draws: list[list[tuple[dict, np.ndarray, int]]] = []
+    draws: list[list[np.ndarray]] = []
     pad_to = max(pad_of(i) for i in active)
     for i in active:
         row = []
         for _ in range(k_steps):
-            b, m, k = poisson_batch(rng, participants[i], rate_of(i),
-                                    pad_of(i))
-            pad_to = max(pad_to, len(m))
-            row.append((b, m, k))
+            idx, grown = poisson_rows(rng, len(participants[i]), rate_of(i),
+                                      pad_of(i))
+            pad_to = max(pad_to, grown)
+            row.append(idx)
         draws.append(row)
     if executor is not None:
         pad_to = executor.round_pad(pad_to)
 
-    def gather(fn):
-        return np.stack([
-            np.stack([fn(d) for d in row]) for row in draws
-        ])
-
-    x = gather(lambda d: _repad(d[0]["x"], pad_to))
-    y = gather(lambda d: _repad(d[0]["y"], pad_to))
-    masks = gather(lambda d: _repad(d[1], pad_to))
-    counts = np.asarray(
-        [[d[2] for d in row] for row in draws], np.int32
-    )
+    starts = _row_starts(participants)
+    shape = (len(active), k_steps, pad_to)
+    rows = np.full(shape, starts[-1], np.int32)
+    masks = np.zeros(shape, np.float32)
+    for s, (i, row) in enumerate(zip(active, draws)):
+        for k, idx in enumerate(row):
+            rows[s, k, :len(idx)] = starts[i] + idx
+            masks[s, k, :len(idx)] = 1.0
+    counts = np.asarray([[len(idx) for idx in row] for row in draws],
+                        np.int32)
     sizes = [int(c) for c in counts.sum(axis=1)]
     if steps is None:  # collapse the singleton steps axis
-        x, y, masks, counts = x[:, 0], y[:, 0], masks[:, 0], counts[:, 0]
-    if executor is not None:
-        example_axis = 1 if steps is None else 2
-        for arr in (x, y, masks):
-            executor.mark(arr, axis=example_axis)
+        rows, masks, counts = rows[:, 0], masks[:, 0], counts[:, 0]
+    cb = CohortBatch(masks=masks, counts=counts, sizes=sizes, rows=rows,
+                     source=participants)
+    if host_stack or executor is not None:
+        x, y = cb.x, cb.y
+        if executor is not None:
+            example_axis = 1 if steps is None else 2
+            for arr in (x, y, masks):
+                executor.mark(arr, axis=example_axis)
     obs.complete("host_rng.stack_poisson", t0, cat="rng",
-                 cohort=len(active), pad=pad_to)
-    return CohortBatch(x=x, y=y, masks=masks, counts=counts, sizes=sizes)
+                 cohort=len(active), pad=pad_to,
+                 host_bytes=sum(a.nbytes for a in cb.stacked.values()))
+    return cb
 
 
 # -- in-jit cohort reductions ------------------------------------------------

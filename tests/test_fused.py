@@ -7,8 +7,10 @@ fused path itself is covered by ``tests/test_arms_equivalence.py`` (both
 backends run the same fused program).
 """
 
+import contextlib
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -160,6 +162,138 @@ def test_stack_poisson_grows_pad_for_the_whole_cohort():
     cb = fused.stack_poisson(rng, silos, [0, 1], 0.9, 8)  # rate 0.9 >> pad 8
     assert cb.x.shape[1] >= 64  # grown to a power of two that fits
     assert (cb.masks.sum(axis=1) == np.asarray(cb.sizes)).all()
+
+
+class _PlainJitExecutor:
+    """An executor that runs each program as plain jit on one device: it
+    takes ``DeCaPHArm.fused_round`` down its mesh branch, which feeds the
+    program the batch stacked on the host."""
+
+    def round_pad(self, pad):
+        return pad
+
+    def mark(self, arr, axis):
+        pass
+
+    def execute(self, wrapper, args, kwargs):
+        return wrapper.jitted(*args, **kwargs)
+
+
+def _decaph(**kw):
+    return arms.get("decaph")(_make_model(5), _silos(), _cfg(**kw))
+
+
+def _decaph_round(arm, seed, *, host_stack, need_reduced):
+    rng = np.random.default_rng(seed)
+    ctx = (fused.execution_context(_PlainJitExecutor()) if host_stack
+           else contextlib.nullcontext())
+    with ctx:
+        contribs, out = arm.fused_round(
+            arm.init_params(), [0, 1, 2, 3], 0, rng, 4, need_payloads=True,
+            need_reduced=need_reduced)
+    return contribs, out, rng
+
+
+@pytest.mark.parametrize("max_pad", [None, 4], ids=["fits", "outgrows"])
+@pytest.mark.parametrize("secagg", [True, False],
+                         ids=["secagg", "nosecagg"])
+def test_device_gather_round_bit_identical_to_host_stack(secagg, max_pad):
+    """The batch gathered in the program from the rows on the device gives
+    the host-stacked round's payloads, reduced sum, losses and sizes bit
+    for bit; with a pad of 4 every draw outgrows it (the retrace)."""
+    arm = _decaph(use_secagg=secagg, max_pad_batch=max_pad)
+    dev, dev_out, _ = _decaph_round(arm, 5, host_stack=False,
+                                    need_reduced=not secagg)
+    host, host_out, _ = _decaph_round(arm, 5, host_stack=True,
+                                      need_reduced=not secagg)
+    if max_pad is not None:
+        assert max(c.size for c in dev.values()) > max_pad
+    assert sorted(dev) == sorted(host) == [0, 1, 2, 3]
+    for i in dev:
+        assert dev[i].size == host[i].size
+        assert dev[i].loss == host[i].loss
+        for a, b in zip(*map(jax.tree_util.tree_leaves,
+                             (dev[i].payload, host[i].payload))):
+            np.testing.assert_array_equal(a, b)
+    # SecAgg on: the payload stack; off: the in-jit reduced sum
+    for a, b in zip(*map(jax.tree_util.tree_leaves, (dev_out, host_out))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("max_pad", [None, 4], ids=["fits", "outgrows"])
+def test_device_gather_round_consumes_rng_like_the_loop(max_pad):
+    from repro.arms.base import poisson_batch
+
+    arm = _decaph(max_pad_batch=max_pad)
+    _, _, rng = _decaph_round(arm, 9, host_stack=False, need_reduced=True)
+    loop_rng = np.random.default_rng(9)
+    for part in arm.participants:
+        poisson_batch(loop_rng, part, arm.rate, arm.pad)
+    assert rng.bit_generator.state == loop_rng.bit_generator.state
+
+
+@pytest.mark.parametrize("steps", [None, 2])
+def test_cohort_batch_stacks_lazily_like_the_loop(steps):
+    """Without ``host_stack`` nothing is stacked until ``x``/``y`` are read;
+    then they hold the loop's batches, zero-padded to the cohort's pad."""
+    from repro.arms.base import poisson_batch
+
+    silos = _silos()
+    rng_b = np.random.default_rng(4)
+    cb = fused.stack_poisson(np.random.default_rng(4), silos, [1, 3], 0.1,
+                             4, steps=steps, host_stack=False)
+    assert cb.stacked == {}
+    pad = cb.rows.shape[-1]
+    for s, i in enumerate([1, 3]):
+        for k in range(steps or 1):
+            b, m, n = poisson_batch(rng_b, silos[i], 0.1, 4)
+            at = (s,) if steps is None else (s, k)
+            for leaf in ("x", "y"):
+                want = np.zeros((pad,) + b[leaf].shape[1:], b[leaf].dtype)
+                want[:len(b[leaf])] = b[leaf]
+                np.testing.assert_array_equal(getattr(cb, leaf)[at], want)
+            np.testing.assert_array_equal(cb.masks[at][:len(m)], m)
+            assert cb.counts[at] == n
+    assert set(cb.stacked) == {"x", "y"} and cb.x is cb.x
+
+
+@pytest.mark.parametrize("arm_name", ["decaph", "fl"])
+def test_stack_poisson_span_counts_the_host_bytes(arm_name):
+    """``host_bytes`` is 0 where the program gathers the batch on the
+    device (DeCaPH), and the stacked examples where the host stacks it."""
+    import repro.obs as obs
+
+    with obs.recording() as rec:
+        rep = _run(arm_name, _cfg(rounds=2))
+    spans = [e for e in rec.events() if e["type"] == "span"
+             and e["name"] == "host_rng.stack_poisson"]
+    assert len(spans) == rep.rounds_completed == 2
+    for e in spans:
+        args = e["args"]
+        assert set(args) == {"cohort", "pad", "host_bytes"}
+        assert args["cohort"] == 4
+        # x: 5 float32 features, y: one float32 label, per slot
+        stacked = args["cohort"] * args["pad"] * (5 + 1) * 4
+        assert args["host_bytes"] == (0 if arm_name == "decaph" else stacked)
+
+
+def test_device_rows_are_arguments_of_the_cohort_program():
+    """The rows on the device enter the gathered cohort program as
+    arguments: no constant of their shape is baked into it."""
+    arm = _decaph()
+    _decaph_round(arm, 0, host_stack=False, need_reduced=True)
+    x_all, y_all = arm._rows
+    assert x_all.shape == (sum(len(p) for p in arm.participants) + 1, 5)
+    cb = fused.stack_poisson(np.random.default_rng(0), arm.participants,
+                             [0, 1, 2, 3], arm.rate, arm.pad,
+                             host_stack=False)
+    text = arm._gather_step.jitted.lower(
+        arm.init_params(), x_all, y_all, cb.rows, cb.masks, np.int32(17),
+        np.arange(4, dtype=np.int32), 4).as_text()
+    shape = "tensor<{}x{}xf32>".format(*x_all.shape)
+    assert shape in text
+    assert [ln for ln in text.splitlines()
+            if "constant" in ln and shape in ln] == []
 
 
 def test_scaffold_beats_fedavg_under_heterogeneity():
